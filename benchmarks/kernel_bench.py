@@ -1,6 +1,8 @@
-"""Pallas kernel micro-benchmarks (interpret mode vs jnp reference).
+"""Pallas kernel micro-benchmarks against the jnp reference.
 
-CPU wall-clock of interpret-mode Pallas is NOT a TPU performance statement —
+The kernels run as ``kernels.ops`` chooses: compiled on the TPU, in
+interpret mode on the CPU.  CPU wall-clock of interpret-mode Pallas is NOT
+a TPU performance statement —
 what matters here is (a) correctness against the ref.py oracle and (b) the
 chosen block mappings (the TPU-native analogue of SNAKE's logical array
 shapes), which are printed as derived metrics.
@@ -25,12 +27,12 @@ def run() -> List[Row]:
         ka, kb = jax.random.split(jax.random.fold_in(key, m))
         a = jax.random.normal(ka, (m, k), jnp.float32)
         b = jax.random.normal(kb, (k, n), jnp.float32)
-        out = ops.decode_gemm(a, b, interpret=True)
+        out = ops.decode_gemm(a, b)
         want = ref.decode_gemm_ref(a, b)
         err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                     - want.astype(jnp.float32))))
         rows.append(Row(f"kernels/decode_gemm_{m}x{n}x{k}_maxerr", err,
-                        note="interpret-mode vs jnp oracle"))
+                        note="kernel vs jnp oracle"))
         mp = ops.decode_gemm_mapping(m, n, k, jnp.float32)
         rows.append(Row(f"kernels/decode_gemm_{m}x{n}x{k}_block_n",
                         float(mp.block_n),
@@ -43,7 +45,7 @@ def run() -> List[Row]:
     kc = jax.random.normal(kk, (b_, s, hkv, d), jnp.float32)
     vc = jax.random.normal(kv, (b_, s, hkv, d), jnp.float32)
     lengths = jnp.array([s, s // 2], jnp.int32)
-    out = ops.attention_decode(q, kc, vc, lengths, interpret=True)
+    out = ops.attention_decode(q, kc, vc, lengths)
     want = ref.flash_decode_ref(q, kc, vc, lengths)
     rows.append(Row("kernels/flash_decode_maxerr",
                     float(jnp.max(jnp.abs(out - want)))))
@@ -58,7 +60,7 @@ def run() -> List[Row]:
                                          jnp.float32)) * 0.9
     u = jax.random.normal(ks[4], (h, dh), jnp.float32) * 0.1
     s0 = jnp.zeros((bw, h, dh, dh), jnp.float32)
-    out, _ = ops.wkv6_scan(r_, kk_, vv, w, u, s0, interpret=True)
+    out, _ = ops.wkv6_scan(r_, kk_, vv, w, u, s0)
     want, _ = ref.wkv6_ref(r_, kk_, vv, w, u, s0)
     rows.append(Row("kernels/wkv6_maxerr",
                     float(jnp.max(jnp.abs(out - want)))))

@@ -9,7 +9,7 @@ from __future__ import annotations
 import itertools
 from typing import Any, Dict, Iterable, Iterator, List, Optional, Set, Tuple
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 Literal = jcore.Literal
 
@@ -22,9 +22,13 @@ SCATTER_PRIMS = frozenset({
 #: comparison primitives that can express a bounds guard
 CMP_PRIMS = frozenset({"lt", "le", "gt", "ge"})
 
+#: call primitives wrapping a nested jaxpr; ``jnp.where`` and most other
+#: ``jnp`` functions trace to a ``jit`` equation around their body
+CALL_PRIMS = frozenset({"jit", "closed_call", "custom_jvp_call"})
+
 
 def subjaxprs(eqn) -> List[Any]:
-    """All jaxprs nested in one equation's params (pjit/cond/scan/...)."""
+    """All jaxprs nested in one equation's params (jit/cond/scan/...)."""
     out = []
     for v in eqn.params.values():
         if hasattr(v, "jaxpr"):            # ClosedJaxpr
@@ -79,10 +83,10 @@ def eqn_mentions_literal(eqn, value, recursive: bool = True) -> bool:
 
 
 def eqn_is_select(eqn) -> bool:
-    """select_n, or a pjit call whose body is a select (jnp.where)."""
+    """select_n, or a call whose body is a select (jnp.where)."""
     if eqn.primitive.name == "select_n":
         return True
-    if eqn.primitive.name in ("pjit", "closed_call", "custom_jvp_call"):
+    if eqn.primitive.name in CALL_PRIMS:
         return any(e.primitive.name == "select_n"
                    for sub in subjaxprs(eqn) for e in iter_eqns(sub))
     return False
@@ -91,7 +95,7 @@ def eqn_is_select(eqn) -> bool:
 def eqn_is_compare(eqn) -> bool:
     if eqn.primitive.name in CMP_PRIMS:
         return True
-    if eqn.primitive.name in ("pjit", "closed_call"):
+    if eqn.primitive.name in CALL_PRIMS:
         return any(e.primitive.name in CMP_PRIMS
                    for sub in subjaxprs(eqn) for e in iter_eqns(sub))
     return False
@@ -216,7 +220,7 @@ def unguarded_writes_to(kernel_jaxpr, target_refs) -> List[Any]:
                     sub = br.jaxpr if hasattr(br, "jaxpr") else br
                     sub_env = _bind(sub.invars, operands, env)
                     walk(sub, sub_env, True)
-            elif name in ("scan", "while", "pjit", "closed_call"):
+            elif name in ("scan", "while") or name in CALL_PRIMS:
                 for key in ("jaxpr", "body_jaxpr", "cond_jaxpr"):
                     cj = eqn.params.get(key)
                     if cj is None:

@@ -60,7 +60,7 @@ def _f(invariant: str, message: str, file=None, line=None, detail=None):
 def _has_window_compare(eqns, nblk: int) -> bool:
     """A comparison primitive carrying the window length as *its own*
     literal operand (searched through nested jaxprs eqn-by-eqn, so an
-    unrelated pjit that happens to contain both a compare and the
+    unrelated jit call that happens to contain both a compare and the
     constant elsewhere does not satisfy the guard)."""
     for e in eqns:
         if e.primitive.name in JU.CMP_PRIMS \
@@ -76,7 +76,7 @@ def _has_window_compare(eqns, nblk: int) -> bool:
 
 def _routes_to_scratch(eqns, scratch_page: int) -> bool:
     """A select in the slice one of whose branches is the scratch page —
-    either as a call-site literal (jnp.where lowers to a pjit taking the
+    either as a call-site literal (jnp.where lowers to a jit call taking the
     scalar) or via a one-hop broadcast/convert of the literal."""
     producers = {ov: e for e in eqns for ov in e.outvars}
     for e in eqns:

@@ -164,8 +164,6 @@ def analyze(compiled, *, arch: str, shape: str, mesh_name: str,
             n_devices: int, model_flops: Optional[float] = None
             ) -> RooflineReport:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # older JAX: one dict per device
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     hbm = float(ca.get("bytes accessed", 0.0))
     prof = parse_collectives(compiled.as_text(), n_devices)

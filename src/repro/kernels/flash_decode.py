@@ -33,6 +33,7 @@ def _round_up(x: int, g: int) -> int:
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             block_s: int, s_steps: int, scale: float):
+    bi = pl.program_id(0)
     si = pl.program_id(2)
 
     @pl.when(si == 0)
@@ -47,7 +48,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * scale  # (G,bs)
     pos = si * block_s + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = pos < len_ref[0, 0]
+    valid = pos < len_ref[bi]
     s = jnp.where(valid, s, NEG)
 
     m_prev = m_ref[:, :1]                          # (G, 1)
@@ -183,30 +184,32 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     vt = jnp.moveaxis(v, 2, 1)
     kt = jnp.pad(kt, ((0, 0), (0, 0), (0, sp - s), (0, 0)))
     vt = jnp.pad(vt, ((0, 0), (0, 0), (0, sp - s), (0, 0)))
-    len2 = lengths.reshape(b, 1).astype(jnp.int32)
-
     s_steps = sp // block_s
     grid = (b, hkv, s_steps)
-    out = pl.pallas_call(
-        functools.partial(_kernel, block_s=block_s, s_steps=s_steps,
-                          scale=scale),
+    spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                    # lengths
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bi, hi, si: (bi, 0)),
-            pl.BlockSpec((1, 1, gp, d), lambda bi, hi, si: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, gp, d),
+                         lambda bi, hi, si, ln: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, block_s, d),
-                         lambda bi, hi, si: (bi, hi, si, 0)),
+                         lambda bi, hi, si, ln: (bi, hi, si, 0)),
             pl.BlockSpec((1, 1, block_s, d),
-                         lambda bi, hi, si: (bi, hi, si, 0)),
+                         lambda bi, hi, si, ln: (bi, hi, si, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, gp, d),
-                               lambda bi, hi, si: (bi, hi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
+                               lambda bi, hi, si, ln: (bi, hi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((gp, d), jnp.float32),
             pltpu.VMEM((gp, 128), jnp.float32),
             pltpu.VMEM((gp, 128), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, block_s=block_s, s_steps=s_steps,
+                          scale=scale),
+        grid_spec=spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
         interpret=interpret,
-    )(len2, qr, kt, vt)
+    )(lengths.astype(jnp.int32), qr, kt, vt)
     return out[:, :, :g, :].reshape(b, hq, d)

@@ -1,12 +1,12 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels run with interpret=True; on real TPU
-set ``REPRO_PALLAS_INTERPRET=0`` (or rely on the default platform check).
+The kernels are compiled for the TPU.  On the CPU backend, where the
+tests run, they run in Pallas interpret mode; any other backend is an
+error.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -19,10 +19,11 @@ from repro.kernels.wkv6 import wkv6 as _wkv6
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels target the TPU (interpret mode "
+                           f"on the CPU); backend {backend!r} has neither")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -10,20 +10,9 @@ import jax
 
 
 def _mesh(shape, axes):
-    """Version-portable mesh constructor.
-
-    ``jax.sharding.AxisType`` (and ``make_mesh``'s ``axis_types`` kwarg)
-    only exist in some JAX releases; every axis we use is Auto anyway, which
-    is the default, so fall back to the plain constructor when absent.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        try:
-            return jax.make_mesh(
-                shape, axes,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-        except TypeError:   # make_mesh without axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    """Mesh with every axis Auto (GSPMD decides the layouts)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

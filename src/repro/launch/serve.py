@@ -4,10 +4,11 @@
       --rate 4 --n-requests 12 --prompt-len 32     # reduced config default
 
 ``--pallas`` routes decode attention through the flash-decode Pallas kernel
-(interpret mode on CPU, compiled on TPU); with ``--paged`` it becomes the
-block-table read-through paged kernel.  ``--paged`` switches KV residency
-to the page-pool layout (``--page-size``, ``--num-pages`` to oversubscribe)
-and ``--prefill-chunk`` interleaves Sarathi prefill chunks with the hot
+(compiled on the TPU; Pallas interpret mode on the CPU backend, where the
+tests run); with ``--paged`` it becomes the block-table read-through
+paged kernel.  ``--paged`` switches KV residency to the page-pool layout
+(``--page-size``, ``--num-pages`` to oversubscribe) and
+``--prefill-chunk`` interleaves Sarathi prefill chunks with the hot
 decode batch (written directly into block-table pages on the paged
 engine).  ``--fuse-steps K`` (paged only) fuses up to K decode steps
 into one device-resident ``lax.scan`` tick — the host surfaces only at
@@ -47,6 +48,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.serving.engine import (EngineConfig, load_trace, make_engine,
                                   make_grouped_prefix_trace,
@@ -198,6 +200,7 @@ def main():
                      f"({args.replicas})")
         tiers = (p_n, d_n)
 
+    enable_compile_cache()
     entry = registry.get(args.arch, reduced=not args.full)
     ecfg = EngineConfig(max_batch=args.max_batch,
                         max_seq=args.prompt_len + args.max_new + 2,

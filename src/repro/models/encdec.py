@@ -78,8 +78,8 @@ def init(key, cfg: ArchConfig, tp: int = 1) -> Params:
         jax.random.split(kdec, cfg.num_layers))
     return {"embed": L.init_embed(ke, cfg.padded_vocab(tp), cfg.d_model,
                                   dtype, tie=True),
-            "pos_dec": (jax.random.normal(kp, (cfg.max_seq, cfg.d_model),
-                                          jnp.float32) * 0.01).astype(dtype),
+            "pos_dec": L.normal_init(kp, (cfg.max_seq, cfg.d_model), dtype,
+                                     0.01),
             "enc": enc, "dec": dec,
             "ln_enc": L.init_norm("layernorm", cfg.d_model),
             "ln_f": L.init_norm("layernorm", cfg.d_model)}

@@ -83,9 +83,19 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
 # ---------------------------------------------------------------------------
 # Dense / attention initialisation
 # ---------------------------------------------------------------------------
+def normal_init(key, shape, dtype, scale: float) -> jax.Array:
+    """N(0, scale^2) draw in float32, cast to ``dtype``.
+
+    The ``where`` is an identity (a normal draw is never NaN).  It keeps
+    XLA from folding ``scale`` into the sqrt(2) that ends the draw, which
+    would change the last bit: so a jitted init gives exactly the values
+    of an op-by-op one."""
+    x = jax.random.normal(key, shape, jnp.float32)
+    return (jnp.where(jnp.isnan(x), 0.0, x) * scale).astype(dtype)
+
+
 def dense_init(key, d_in: int, d_out: int, dtype, scale: float = 0.02):
-    return (jax.random.normal(key, (d_in, d_out), jnp.float32)
-            * scale).astype(dtype)
+    return normal_init(key, (d_in, d_out), dtype, scale)
 
 
 def init_attention(key, cfg, dtype, hq: int, hkv: int) -> Params:
@@ -292,12 +302,9 @@ def init_moe(key, cfg, dtype) -> Params:
     scale_down = 0.02 / math.sqrt(2 * cfg.num_layers)
     p = {
         "router": dense_init(ks[0], d, e, jnp.float32),
-        "w_up": (jax.random.normal(ks[1], (e, d, f), jnp.float32)
-                 * 0.02).astype(dtype),
-        "w_gate": (jax.random.normal(ks[2], (e, d, f), jnp.float32)
-                   * 0.02).astype(dtype),
-        "w_down": (jax.random.normal(ks[3], (e, f, d), jnp.float32)
-                   * scale_down).astype(dtype),
+        "w_up": normal_init(ks[1], (e, d, f), dtype, 0.02),
+        "w_gate": normal_init(ks[2], (e, d, f), dtype, 0.02),
+        "w_down": normal_init(ks[3], (e, f, d), dtype, scale_down),
     }
     if cfg.num_shared_experts:
         p["shared"] = init_ffn(ks[4], d,
@@ -394,7 +401,6 @@ def _apply_moe_shardmap(p: Params, x: jax.Array, cfg, mesh) -> jax.Array:
     The partial expert outputs are summed with lax.psum — the Megatron-EP
     combine, and the paper's Fig. 9 RS/AG stage.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import data_axes
     daxes = data_axes(mesh)
@@ -437,13 +443,13 @@ def _apply_moe_shardmap(p: Params, x: jax.Array, cfg, mesh) -> jax.Array:
 
     wspec = P(None, "model", None, None) if p["w_up"].ndim == 4 \
         else P("model", None, None)
-    return shard_map(
+    return jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(P(dp, None), P(None, None), wspec, wspec,
                   P(None, "model", None, None) if p["w_down"].ndim == 4
                   else P("model", None, None)),
         out_specs=P(dp, None),
-        check_rep=False)(x, p["router"], p["w_up"], p["w_gate"],
+        check_vma=False)(x, p["router"], p["w_up"], p["w_gate"],
                          p["w_down"])
 
 

@@ -68,8 +68,7 @@ def _init_rec_layer(key, cfg: ArchConfig, dtype) -> Params:
         "ln": L.init_norm(cfg.norm, d),
         "w_in_x": L.dense_init(ks[0], d, w, dtype),
         "w_in_gate": L.dense_init(ks[1], d, w, dtype),
-        "conv_w": (jax.random.normal(ks[2], (cfg.conv_width, w), jnp.float32)
-                   * 0.02).astype(dtype),
+        "conv_w": L.normal_init(ks[2], (cfg.conv_width, w), dtype, 0.02),
         "conv_b": jnp.zeros((w,), dtype),
         "lru": {
             "lam": jax.random.uniform(ks[3], (w,), jnp.float32, 0.9, 0.999),

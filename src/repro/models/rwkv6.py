@@ -65,8 +65,8 @@ def _init_layer(key, cfg: ArchConfig, dtype) -> Params:
             "a": L.dense_init(ks[6], d, lora, dtype),
             "b": L.dense_init(ks[7], lora, d, dtype),
         },
-        "u_bonus": (jax.random.normal(ks[8], (heads, cfg.rwkv_head_size),
-                                      jnp.float32) * 0.1),
+        "u_bonus": L.normal_init(ks[8], (heads, cfg.rwkv_head_size),
+                                 jnp.float32, 0.1),
         "gn": {"scale": jnp.ones((d,), jnp.float32),
                "bias": jnp.zeros((d,), jnp.float32)},
         "cm": {

@@ -40,12 +40,15 @@ axis 0, higher-rank leaves on axis 1 (layer dim leads).  Recurrent
 families have no sequence leaves, so their paged cache degenerates to the
 (already proportional) slot-dense layout.
 
-On CPU this drives reduced configs end-to-end (see examples/serve_decode.py
-and launch/serve.py); under a production mesh the same engine runs with the
-shardings from ``launch.steps.assemble_shardings``.
+An engine lives on one device (``device``, default the first): its
+parameters are built there by a jitted ``init``, and its cache or page
+pool and block tables are placed there too, so replicas behind the router
+can each hold their own chip.  On CPU this drives reduced configs
+end-to-end (see examples/serve_decode.py and launch/serve.py).
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
@@ -129,6 +132,14 @@ class EngineConfig:
     fuse_steps: int = 1
 
 
+@functools.partial(jax.jit, static_argnums=(0, 2, 3))
+def _init_params(module, key, cfg, tp: int):
+    """``module.init`` compiled, so each weight is written once, in its own
+    dtype, on the device that holds ``key``.  Run eagerly, init built every
+    stacked matrix as float32 first: a 5.8 GB ``w_down`` at Yi-6B width."""
+    return module.init(key, cfg, tp)
+
+
 def _insert_slot(cache, new, slot: int):
     """Write request-0 of ``new`` (batch=1 prefill output) into ``slot``."""
     def one(c, n):
@@ -142,14 +153,14 @@ class ServingEngine:
     """Fixed-slot dense-cache engine (the seed layout)."""
 
     def __init__(self, entry: registry.ArchEntry, ecfg: EngineConfig,
-                 tp: int = 1, mesh=None):
+                 tp: int = 1, device=None):
         self.entry = entry
         self.cfg = entry.config
         self.ecfg = ecfg
         self.tp = tp
-        self.mesh = mesh
-        key = jax.random.PRNGKey(0)
-        self.params = entry.module.init(key, self.cfg, tp)
+        self.device = device if device is not None else jax.devices()[0]
+        key = jax.device_put(jax.random.PRNGKey(0), self.device)
+        self.params = _init_params(entry.module, key, self.cfg, tp)
         self.free_slots = list(range(ecfg.max_batch))
         self.active: Dict[int, RequestState] = {}
         self.completed: List[RequestState] = []
@@ -214,8 +225,9 @@ class ServingEngine:
 
     # -- cache backend hooks (overridden by PagedServingEngine) ------------
     def _init_cache(self):
-        self.cache = self.entry.cache_zeros(self.ecfg.max_batch,
-                                            self.ecfg.max_seq, self.tp)
+        self.cache = jax.device_put(
+            self.entry.cache_zeros(self.ecfg.max_batch, self.ecfg.max_seq,
+                                   self.tp), self.device)
 
     def _claim(self, req: RequestState) -> Optional[int]:
         """Reserve a slot (and, when paged, the prompt's pages; with
@@ -591,7 +603,8 @@ class PagedServingEngine(ServingEngine):
                                 share=ecfg.prefix_sharing,
                                 placement=pmap,
                                 placement_policy=(ecfg.placement
-                                                  or "free-first"))
+                                                  or "free-first"),
+                                device=self.device)
         # PagedCache rounds max_seq up to a whole number of pages; adopt
         # the rounded value so prefill buffers, gather views and occupancy
         # math all agree with the table capacity (kv_report asserts this)
@@ -1241,6 +1254,6 @@ class PagedServingEngine(ServingEngine):
 
 
 def make_engine(entry: registry.ArchEntry, ecfg: EngineConfig,
-                tp: int = 1, mesh=None) -> ServingEngine:
+                tp: int = 1, device=None) -> ServingEngine:
     cls = PagedServingEngine if ecfg.paged else ServingEngine
-    return cls(entry, ecfg, tp=tp, mesh=mesh)
+    return cls(entry, ecfg, tp=tp, device=device)

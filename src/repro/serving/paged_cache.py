@@ -524,6 +524,7 @@ class PagedCache:
     share: bool = False
     placement: Optional[PlacementMap] = None
     placement_policy: str = "free-first"
+    device: Any = None     # where the pools and tables live (None: default)
 
     def __post_init__(self):
         if self.page_size <= 0:
@@ -553,9 +554,11 @@ class PagedCache:
                 # axis, add the page axis (+1 scratch page at index P)
                 shape = (leaf.shape[0], self.num_pages + 1,
                          self.page_size) + leaf.shape[3:]
-                store.append(jnp.zeros(shape, leaf.dtype))
+                store.append(jnp.zeros(shape, leaf.dtype,
+                                       device=self.device))
             else:
-                store.append(leaf)   # dense slot layout, as allocated
+                # dense slot layout, as allocated
+                store.append(jax.device_put(leaf, self.device))
         # non-seq leaves don't depend on max_seq, so the probe-sized
         # cache_zeros call above produced them at exactly the right shape
         self.store = store
@@ -593,7 +596,7 @@ class PagedCache:
         if self._tables_dev is None:
             # unmapped entries -> scratch page P (safe for gather/scatter)
             t = np.where(self.tables < 0, self.num_pages, self.tables)
-            self._tables_dev = jnp.asarray(t, jnp.int32)
+            self._tables_dev = jnp.asarray(t, jnp.int32, device=self.device)
         return self._tables_dev
 
     def _mirror_row(self, slot: int) -> None:
@@ -1221,6 +1224,8 @@ class PagedCache:
         for pool, seq, payload, col in zip(self.store, self.is_seq,
                                            shipment.seq_payload,
                                            shipment.slot_payload):
+            # the shipment may come from a replica on another device
+            payload, col = jax.device_put((payload, col), pool.sharding)
             if seq:
                 if fresh:
                     pool = pool.at[:, dst_idx].set(payload[:, src_idx])
@@ -1240,6 +1245,7 @@ class PagedCache:
         """Recurrent families: no pages — just restore the slot column."""
         new_store = []
         for pool, col in zip(self.store, shipment.slot_payload):
+            col = jax.device_put(col, pool.sharding)
             if pool.ndim == 1:
                 new_store.append(pool.at[slot].set(col))
             else:

@@ -56,15 +56,8 @@ lists are pinned in ``analysis/checks/mirror_spec.py``).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
-
-try:                            # Protocol: py3.8+; fall back quietly
-    from typing import Protocol, runtime_checkable
-except ImportError:             # pragma: no cover - py3.7 safety net
-    Protocol = object           # type: ignore
-
-    def runtime_checkable(cls):  # type: ignore
-        return cls
+from typing import (Any, Dict, List, Optional, Protocol, Tuple,
+                    runtime_checkable)
 
 
 @dataclass(frozen=True)

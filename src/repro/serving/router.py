@@ -352,27 +352,33 @@ def make_cluster(entry, ecfg, n_replicas: int, tp: int = 1,
                  tiers: Optional[Tuple[int, int]] = None) -> Router:
     """Build N identical engine replicas behind a :class:`Router`.
 
-    Each replica gets its OWN ``EngineConfig`` copy (the paged engine
-    adopts the page-rounded ``max_seq`` in place) and its own page pool /
-    slots.  All replicas are initialized from the same PRNG seed, so
-    their parameters are identical and — with ``share_compiled`` — the
-    first replica's parameter pytree and jitted prefill/decode/extend
-    callables are shared by the rest instead of re-initializing and
-    recompiling per replica.
+    Replica ``i`` lives on ``jax.devices()[i % n_devices]``: its
+    parameters, page pool and block tables are placed there.  Each
+    replica gets its OWN ``EngineConfig`` copy (the paged engine adopts
+    the page-rounded ``max_seq`` in place) and its own page pool / slots.
+    All replicas are initialized from the same PRNG seed, so their
+    parameters are identical; with ``share_compiled`` the jitted
+    prefill/decode/extend callables of the first replica serve the rest,
+    and replicas that share a device share one parameter pytree.
 
     ``tiers=(P, D)`` disaggregates the cluster (``P + D == n_replicas``;
     requires a paged config — page shipping moves block-table rows).
     """
+    import jax
+
     from repro.serving.engine import make_engine
     if tiers is not None and not ecfg.paged:
         raise ValueError("tiers requires a paged EngineConfig "
                          "(page shipping moves KV pages)")
-    engines = [make_engine(entry, replace(ecfg), tp=tp)
-               for _ in range(n_replicas)]
+    devices = jax.devices()
+    engines = [make_engine(entry, replace(ecfg), tp=tp,
+                           device=devices[i % len(devices)])
+               for i in range(n_replicas)]
     if share_compiled:
         first = engines[0]
-        for eng in engines[1:]:
-            eng.params = first.params
+        by_device = {}
+        for eng in engines:
+            eng.params = by_device.setdefault(eng.device, eng.params)
             eng._prefill = first._prefill
             eng._decode = first._decode
             eng._extend = first._extend
