@@ -95,8 +95,7 @@ def _run_config(entry, batch: int, fuse: int, max_new: int,
     fr = eng.fused_report()
     return {"tokens_per_s": max(tok_s), "tokens_per_s_reps": tok_s,
             "_tokens": tokens, "fused_ticks": fr.get("fused_ticks", 0),
-            "fused_steps_mean": fr.get("fused_steps_mean", 0.0),
-            "host_frac": fr.get("host_frac", 0.0)}
+            "fused_steps_mean": fr.get("fused_steps_mean", 0.0)}
 
 
 def run(smoke: bool = False) -> List[Row]:
@@ -124,8 +123,6 @@ def run(smoke: bool = False) -> List[Row]:
                             m["tokens_per_s"]))
             rows.append(Row(f"serving_fused/{tag}/speedup_vs_per_tick",
                             speedup))
-            rows.append(Row(f"serving_fused/{tag}/host_frac",
-                            m["host_frac"]))
             rows.append(Row(f"serving_fused/{tag}/fused_steps_mean",
                             m["fused_steps_mean"]))
             artifact["configs"][tag] = {
@@ -134,7 +131,6 @@ def run(smoke: bool = False) -> List[Row]:
                 "speedup_vs_per_tick": speedup,
                 "fused_ticks": m["fused_ticks"],
                 "fused_steps_mean": m["fused_steps_mean"],
-                "host_frac": m["host_frac"],
                 "tokens_identical_to_per_tick": fuse == fuses[0] or
                 m["_tokens"] == base["_tokens"],
             }

@@ -132,8 +132,6 @@ SCHEDULER_METRICS_ONLY = {
     "codesign_substrate": "echoed config, not a metric",
     "modeled_tokens_per_s": "derived from decoded_tokens / makespan_s on "
                             "the sim side",
-    "fused_host_frac": "wall-clock host/device split only exists on the "
-                       "live path",
     "hists": "bucketed distribution summaries from the live metrics "
              "registry; the sim reports scalar statistics",
 }

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -113,6 +113,13 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                        / jnp.maximum(l_ref[:, :1], 1e-20)).astype(o_ref.dtype)
 
 
+def paged_grid(q_shape, pool_shape, tables_shape) -> Tuple[int, int, int]:
+    """The paged kernel's grid, ``(b, hkv, nblk)``, from the shapes of its
+    operands: one program per request, KV head and block-table entry,
+    whatever the lengths.  Each program reads one page of K and one of V."""
+    return q_shape[0], pool_shape[2], tables_shape[1]
+
+
 def paged_flash_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                        tables: jax.Array, lengths: jax.Array,
                        interpret: bool = False) -> jax.Array:
@@ -125,9 +132,10 @@ def paged_flash_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     On real TPUs the page size should be a multiple of the sublane count
     (8 fp32 / 16 bf16) so each page DMA is tile-aligned.
     """
-    b, hq, d = q.shape
-    npages, page, hkv, _ = k_pool.shape
-    nblk = tables.shape[1]
+    _, hq, d = q.shape
+    page = k_pool.shape[1]
+    grid = paged_grid(q.shape, k_pool.shape, tables.shape)
+    b, hkv, nblk = grid
     g = hq // hkv
     gp = _round_up(g, _sublane(q.dtype))
     scale = 1.0 / math.sqrt(d)
@@ -137,7 +145,6 @@ def paged_flash_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     kt = jnp.moveaxis(k_pool, 2, 1)               # (P+1, Hkv, page, D)
     vt = jnp.moveaxis(v_pool, 2, 1)
 
-    grid = (b, hkv, nblk)
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                    # tables, lengths
         grid=grid,

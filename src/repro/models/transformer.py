@@ -268,21 +268,26 @@ def extend_step(params: Params, cfg: ArchConfig, tokens: jax.Array,
                          cfg.mrope_sections if cfg.mrope else None)
         kc = lax.dynamic_index_in_dim(kc_all, li, 0, keepdims=False)
         vc = lax.dynamic_index_in_dim(vc_all, li, 0, keepdims=False)
-        kc = lax.dynamic_update_slice(kc, k, (0, offset, 0, 0))
-        vc = lax.dynamic_update_slice(vc, v, (0, offset, 0, 0))
+        with jax.named_scope("kv_write"):
+            kc = lax.dynamic_update_slice(kc, k, (0, offset, 0, 0))
+            vc = lax.dynamic_update_slice(vc, v, (0, offset, 0, 0))
         # the causal mask (q_offset) blanks everything past the current
         # position, including stale/zero future cache slots
-        attn = L.blocked_attention(q, kc, vc, causal=True,
-                                   window=cfg.window, q_offset=offset)
+        with jax.named_scope("attention"):
+            attn = L.blocked_attention(q, kc, vc, causal=True,
+                                       window=cfg.window, q_offset=offset)
         x = x + attn.reshape(b, c, hq * cfg.d_head) @ lp["attn"]["wo"]
-        h2 = L.apply_norm(cfg.norm, lp["ln2"], x)
-        if cfg.num_experts:
-            y = L.apply_moe(lp["moe"], h2.reshape(b * c, cfg.d_model), cfg)
-            y = y.reshape(b, c, cfg.d_model)
-        else:
-            y = L.apply_ffn(lp["ffn"], h2, cfg.act)
-        kc_all = lax.dynamic_update_index_in_dim(kc_all, kc, li, 0)
-        vc_all = lax.dynamic_update_index_in_dim(vc_all, vc, li, 0)
+        with jax.named_scope("ffn"):
+            h2 = L.apply_norm(cfg.norm, lp["ln2"], x)
+            if cfg.num_experts:
+                y = L.apply_moe(lp["moe"], h2.reshape(b * c, cfg.d_model),
+                                cfg)
+                y = y.reshape(b, c, cfg.d_model)
+            else:
+                y = L.apply_ffn(lp["ffn"], h2, cfg.act)
+        with jax.named_scope("kv_write"):
+            kc_all = lax.dynamic_update_index_in_dim(kc_all, kc, li, 0)
+            vc_all = lax.dynamic_update_index_in_dim(vc_all, vc, li, 0)
         return (x + y, kc_all, vc_all)
 
     x, k_new, v_new = lax.fori_loop(0, cfg.num_layers, body,
@@ -291,6 +296,14 @@ def extend_step(params: Params, cfg: ArchConfig, tokens: jax.Array,
     h_last = L.apply_norm(cfg.norm, params["ln_f"], x[:, -1])
     logits = L.unembed(params["embed"], h_last)
     return logits, KVCache(k_new, v_new, cache.lengths + c)
+
+
+def _decode_ffn(cfg: ArchConfig, lp: Params, x: jax.Array) -> jax.Array:
+    """One decode token's FFN (or MoE) residual branch, post-norm."""
+    h2 = L.apply_norm(cfg.norm, lp["ln2"], x)
+    if cfg.num_experts:
+        return L.apply_moe(lp["moe"], h2, cfg)
+    return L.apply_ffn(lp["ffn"], h2, cfg.act)
 
 
 def decode_step_paged(params: Params, cfg: ArchConfig, tokens: jax.Array,
@@ -340,17 +353,17 @@ def decode_step_paged(params: Params, cfg: ArchConfig, tokens: jax.Array,
                          cfg.mrope_sections if cfg.mrope else None)
         k = L.apply_rope(k, positions, cfg.rope_theta,
                          cfg.mrope_sections if cfg.mrope else None)
-        kc = kc.at[page, off].set(k[:, 0])               # (B,) pages/offs
-        vc = vc.at[page, off].set(v[:, 0])
-        attn = attn_fn(q[:, 0], kc, vc, tables, lengths + 1)
+        with jax.named_scope("kv_write"):
+            kc = kc.at[page, off].set(k[:, 0])           # (B,) pages/offs
+            vc = vc.at[page, off].set(v[:, 0])
+        with jax.named_scope("attention"):
+            attn = attn_fn(q[:, 0], kc, vc, tables, lengths + 1)
         x = x + attn.reshape(b, hq * cfg.d_head) @ lp["attn"]["wo"]
-        h2 = L.apply_norm(cfg.norm, lp["ln2"], x)
-        if cfg.num_experts:
-            y = L.apply_moe(lp["moe"], h2, cfg)
-        else:
-            y = L.apply_ffn(lp["ffn"], h2, cfg.act)
-        kp = lax.dynamic_update_index_in_dim(kp, kc, li, 0)
-        vp = lax.dynamic_update_index_in_dim(vp, vc, li, 0)
+        with jax.named_scope("ffn"):
+            y = _decode_ffn(cfg, lp, x)
+        with jax.named_scope("kv_write"):
+            kp = lax.dynamic_update_index_in_dim(kp, kc, li, 0)
+            vp = lax.dynamic_update_index_in_dim(vp, vc, li, 0)
         return (x + y, kp, vp)
 
     x, kp, vp = lax.fori_loop(0, cfg.num_layers, body,
@@ -438,21 +451,21 @@ def decode_step(params: Params, cfg: ArchConfig, tokens: jax.Array,
                          cfg.mrope_sections if cfg.mrope else None)
         # write new k/v at each request's current length
         idx = cache.lengths                              # (B,)
-        kc = jax.vmap(lambda c, kn, i: lax.dynamic_update_slice_in_dim(
-            c, kn, i, axis=0))(kc, k[:, 0:1], idx)
-        vc = jax.vmap(lambda c, vn, i: lax.dynamic_update_slice_in_dim(
-            c, vn, i, axis=0))(vc, v[:, 0:1], idx)
-        attn = attn_fn(q[:, 0], kc, vc, cache.lengths + 1)  # (B, Hq, D)
+        with jax.named_scope("kv_write"):
+            kc = jax.vmap(lambda c, kn, i: lax.dynamic_update_slice_in_dim(
+                c, kn, i, axis=0))(kc, k[:, 0:1], idx)
+            vc = jax.vmap(lambda c, vn, i: lax.dynamic_update_slice_in_dim(
+                c, vn, i, axis=0))(vc, v[:, 0:1], idx)
+        with jax.named_scope("attention"):
+            attn = attn_fn(q[:, 0], kc, vc, cache.lengths + 1)  # (B,Hq,D)
         x = x + attn.reshape(b, hq * cfg.d_head) @ lp["attn"]["wo"]
-        h2 = L.apply_norm(cfg.norm, lp["ln2"], x)
-        if cfg.num_experts:
-            y = L.apply_moe(lp["moe"], h2, cfg)
-        else:
-            y = L.apply_ffn(lp["ffn"], h2, cfg.act)
+        with jax.named_scope("ffn"):
+            y = _decode_ffn(cfg, lp, x)
         # in-place cache update: a scan emitting stacked (k, v) outputs
         # would materialize a SECOND full cache in temp (§Perf iter. 17)
-        kc_all = lax.dynamic_update_index_in_dim(kc_all, kc, li, 0)
-        vc_all = lax.dynamic_update_index_in_dim(vc_all, vc, li, 0)
+        with jax.named_scope("kv_write"):
+            kc_all = lax.dynamic_update_index_in_dim(kc_all, kc, li, 0)
+            vc_all = lax.dynamic_update_index_in_dim(vc_all, vc, li, 0)
         return (x + y, kc_all, vc_all)
 
     x, k_new, v_new = lax.fori_loop(0, cfg.num_layers, body,

@@ -191,7 +191,7 @@ SCHEDULER_METRIC_CONTRACT: Tuple[str, ...] = (
     "kv_gather_cost_mean_s", "kv_gather_concentration", "kv_region_peak",
     "codesign_substrate", "modeled_time_s", "modeled_tokens_per_s",
     "reconfigurations", "substrate_configs", "array_util_mean",
-    "fused_ticks", "fused_steps_mean", "fused_host_frac", "hists",
+    "fused_ticks", "fused_steps_mean", "hists",
 )
 
 ROUTER_METRIC_CONTRACT: Tuple[str, ...] = (

@@ -18,11 +18,23 @@ Timestamps are seconds on the *emitting* clock relative to the tracer's
 origin: wall ``time.perf_counter`` for the live engine (origin = first
 event), the modeled clock for the sims (construct with ``t0=0.0``).
 ``dur`` is the span length; instantaneous events carry ``dur == 0``.
+
+The live engine opens its spans with :meth:`Tracer.span`: for its
+extent a span is also a ``jax.profiler.TraceAnnotation`` named
+``serve.<kind>``, so a ``jax.profiler`` trace shows the engine's work
+on the device trace's clock, and on exit it emits the same event as
+``emit`` would.  ``phase(name)`` opens a child annotation
+``serve.<kind>.<name>``; ``wait()`` is the phase that blocks on a
+device fetch, and its seconds add up in the event's ``wait_s``.
+Children are profiler annotations only, never events, so span events
+stay disjoint.  The sims emit on their modeled clock and never open
+one.
 Exporters (Perfetto JSON, JSONL save/replay, ``trace_report``) live in
 :mod:`repro.obs.export`.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
@@ -34,14 +46,18 @@ from typing import Any, Dict, List, Optional
 #:                prompt_len)
 #: dispatch       router picked a replica (args: policy)
 #: admit          scheduler admission succeeded (args: requeued)
-#: prefill_chunk  one prefill chunk advanced (args: tokens, pos, last)
-#: decode_step    one per-tick decode iteration (args: batch, finished)
+#: prefill_chunk  one prefill chunk advanced (args: tokens, pos, last;
+#:                wait_s on the last, whose first token is fetched)
+#: decode_step    one per-tick decode iteration (args: batch, finished,
+#:                wait_s, grid_pages, live_pages)
 #: fused_tick     one K-step fused lax.scan tick (args: batch, horizon,
-#:                clamp in {fuse_steps, page_edge, budget}, device_s)
+#:                clamp in {fuse_steps, page_edge, budget}, finished,
+#:                wait_s, grid_pages, live_pages)
 #: grow           on-demand page growth before a decode step (args: pages)
 #: preempt        youngest-first preemption (args: preemptions)
 #: cow_fork       copy-on-write fork of a shared page (args: block, page)
-#: defrag         page-pool compaction ran (args: moved, cost_s)
+#: defrag         page-pool compaction ran (args: live_pages, moved,
+#:                cost_s)
 #: migrate        spilled pages re-homed (args: pages, cost_s)
 #: ship           KV pages shipped between tiers at prefill handoff
 #:                (args: pages, bytes, cost_s, src, dst); sims charge
@@ -52,6 +68,12 @@ from typing import Any, Dict, List, Optional
 #: gauge          per-tick counter sample (args: one value per counter
 #:                track, e.g. free_pages / min_region_free /
 #:                modeled_tokens_per_s)
+#:
+#: wait_s is the seconds a span spent blocked in its device fetch;
+#: grid_pages / live_pages count the paged decode attention's pages in
+#: lane-page units (per layer, per KV head): the kernel's whole grid
+#: for every scan step the device ran, and the pages that held context
+#: for the lanes that emitted a token at that step.
 EVENT_KINDS = (
     "arrival", "dispatch", "admit", "prefill_chunk", "decode_step",
     "fused_tick", "grow", "preempt", "cow_fork", "defrag", "migrate",
@@ -82,10 +104,87 @@ class TraceEvent:
                    args=d.get("args", {}))
 
 
+def _check_kind(kind: str) -> None:
+    if kind not in EVENT_KINDS:
+        raise ValueError(f"unknown trace event kind {kind!r} "
+                         f"(know {EVENT_KINDS})")
+
+
+class Span:
+    """One open span of the live engine (see the module docstring).
+    ``args`` may be filled until the span closes; ``discard()`` closes
+    it without an event."""
+
+    __slots__ = ("_tracer", "kind", "args", "_ann", "_ts", "_keep")
+
+    def __init__(self, tracer, kind: str, args: Dict[str, Any]):
+        self._tracer = tracer
+        self.kind = kind
+        self.args = args
+        self._keep = True
+
+    def __enter__(self) -> "Span":
+        import jax                 # lazily: the sims never open a span
+        self._ann = jax.profiler.TraceAnnotation(f"serve.{self.kind}")
+        self._ann.__enter__()
+        self._ts = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self._keep and exc[0] is None:
+            self._tracer.emit(self.kind, ts=self._ts, dur=t1 - self._ts,
+                              **self.args)
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        import jax
+        with jax.profiler.TraceAnnotation(f"serve.{self.kind}.{name}"):
+            yield self
+
+    @contextlib.contextmanager
+    def wait(self):
+        t0 = time.perf_counter()
+        with self.phase("wait"):
+            yield self
+        self.args["wait_s"] = (self.args.get("wait_s", 0.0)
+                               + time.perf_counter() - t0)
+
+    def discard(self) -> None:
+        self._keep = False
+
+
+class _NullSpan:
+    """The one span of :data:`NULL_TRACER`: opens no annotation, times
+    nothing, emits nothing, and has no ``args`` to fill."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def phase(self, name: str) -> "_NullSpan":
+        return self
+
+    def wait(self) -> "_NullSpan":
+        return self
+
+    def discard(self) -> None:
+        return None
+
+
+_NULL_SPAN = _NullSpan()
+
+
 class NullTracer:
     """No-op tracer: the hot path's default.  ``enabled`` is a plain
     class attribute so the guard is one load + branch; ``emit`` accepts
-    the full signature and drops everything."""
+    the full signature and drops everything, and ``span`` returns one
+    shared no-op span."""
 
     enabled = False
 
@@ -93,6 +192,9 @@ class NullTracer:
              replica: Optional[int] = None, slot: int = -1, rid: int = -1,
              dur: float = 0.0, **args) -> None:
         return None
+
+    def span(self, kind: str, **args) -> _NullSpan:
+        return _NULL_SPAN
 
     def for_replica(self, replica: int) -> "NullTracer":
         return self
@@ -123,9 +225,7 @@ class Tracer(NullTracer):
     def emit(self, kind: str, *, ts: Optional[float] = None,
              replica: Optional[int] = None, slot: int = -1, rid: int = -1,
              dur: float = 0.0, **args) -> None:
-        if kind not in EVENT_KINDS:
-            raise ValueError(f"unknown trace event kind {kind!r} "
-                             f"(know {EVENT_KINDS})")
+        _check_kind(kind)
         if ts is None:
             ts = time.perf_counter()
         if self._t0 is None:
@@ -134,6 +234,13 @@ class Tracer(NullTracer):
             ts=ts - self._t0, kind=kind,
             replica=0 if replica is None else replica,
             slot=slot, rid=rid, dur=dur, args=args))
+
+    def span(self, kind: str, **args) -> Span:
+        """A span of ``kind`` on the wall clock, to use as a context
+        manager; ``args`` (and what is added to ``Span.args`` before it
+        closes) become the event's."""
+        _check_kind(kind)
+        return Span(self, kind, args)
 
     def for_replica(self, replica: int) -> "_BoundTracer":
         """A view of this tracer whose events default to ``replica`` —
@@ -162,6 +269,10 @@ class _BoundTracer:
                           replica=self.replica if replica is None
                           else replica,
                           slot=slot, rid=rid, dur=dur, **args)
+
+    def span(self, kind: str, **args) -> Span:
+        _check_kind(kind)
+        return Span(self, kind, args)
 
     def for_replica(self, replica: int) -> "_BoundTracer":
         return _BoundTracer(self._tracer, replica)

@@ -187,7 +187,9 @@ class ServingEngine:
 
         mod, cfg = entry.module, self.cfg
 
-        def _prefill(params, tokens):
+        # named programs: the device trace's XLA modules read
+        # jit_engine_*, and the host's dispatch events PjitFunction(engine_*)
+        def engine_prefill(params, tokens):
             if cfg.family == "audio":
                 return mod.prefill(params, cfg, tokens,
                                    frames=jnp.zeros((tokens.shape[0],
@@ -202,18 +204,19 @@ class ServingEngine:
             return mod.prefill(params, cfg, tokens, tp=tp,
                                max_seq=ecfg.max_seq)
 
-        def _decode(params, cache, tokens):
+        def engine_decode(params, cache, tokens):
             if attn_fn is not None:
                 return mod.decode_step(params, cfg, tokens, cache, tp=tp,
                                        attn_fn=attn_fn)
             return mod.decode_step(params, cfg, tokens, cache, tp=tp)
 
-        self._prefill = jax.jit(_prefill)
-        self._decode = jax.jit(_decode, donate_argnums=(1,))
+        def engine_extend(params, tokens, cache):
+            return mod.extend_step(params, cfg, tokens, cache, tp=tp)
+
+        self._prefill = jax.jit(engine_prefill)
+        self._decode = jax.jit(engine_decode, donate_argnums=(1,))
         if cfg.family in _ATTN_FAMILIES:
-            self._extend = jax.jit(
-                lambda params, tokens, cache:
-                mod.extend_step(params, cfg, tokens, cache, tp=tp))
+            self._extend = jax.jit(engine_extend)
         else:
             self._extend = None
         self._next_tok = np.zeros((ecfg.max_batch,), np.int32)
@@ -293,23 +296,26 @@ class ServingEngine:
         slot = self._claim(req)
         if slot is None:
             return False
-        t0 = time.perf_counter()
-        tokens = jnp.asarray(req.prompt[None, :])
-        logits, new_cache = self._prefill(self.params, tokens)
-        self._insert(slot, new_cache, len(req.prompt))
-        # argmax on device; the int() fetch is the only synchronization
-        first = int(jnp.argmax(logits[0, : self.cfg.vocab]))
-        self._next_tok[slot] = first
-        req.slot = slot
-        req.prefill_done_s = time.perf_counter() - t0
-        req.first_token_s = time.perf_counter()
-        req.tokens_out.append(first)
-        if self.tracer.enabled:
-            # whole-prompt prefill is one maximal "chunk"
-            self.tracer.emit("prefill_chunk", ts=t0,
-                             dur=req.first_token_s - t0, slot=slot,
-                             rid=req.rid, tokens=len(req.prompt),
-                             pos=len(req.prompt), last=True)
+        tr = self.tracer
+        # whole-prompt prefill is one maximal "chunk"
+        with tr.span("prefill_chunk") as sp:
+            t0 = time.perf_counter()
+            tokens = jnp.asarray(req.prompt[None, :])
+            logits, new_cache = self._prefill(self.params, tokens)
+            self._insert(slot, new_cache, len(req.prompt))
+            # argmax on device; the int() fetch is the only synchronization
+            first = jnp.argmax(logits[0, : self.cfg.vocab])
+            with sp.wait():
+                first = int(first)
+            self._next_tok[slot] = first
+            req.slot = slot
+            req.prefill_done_s = time.perf_counter() - t0
+            req.first_token_s = time.perf_counter()
+            req.tokens_out.append(first)
+            if tr.enabled:
+                sp.args.update(slot=slot, rid=req.rid,
+                               tokens=len(req.prompt),
+                               pos=len(req.prompt), last=True)
         self._activate(slot, req)
         return True
 
@@ -319,46 +325,55 @@ class ServingEngine:
             # prefill-tier engines hold finished prompts for harvest
             # (export_slot_pages) instead of decoding them
             return 0
-        t_step0 = time.perf_counter() if self.tracer.enabled else 0.0
-        batch0 = len(self.active)
-        self._pre_decode_grow()
-        toks = jnp.asarray(self._next_tok)
-        logits = self._decode_batch(toks)
-        # argmax on device, one host fetch per step — dispatch stays
-        # async until the sampled ids are actually needed
-        nxt = np.asarray(jnp.argmax(logits[:, : self.cfg.vocab], axis=-1),
-                         np.int32)
-        now = time.perf_counter()
-        finished = 0
-        for slot, req in list(self.active.items()):
-            tok = int(nxt[slot])
-            req.tokens_out.append(tok)
-            req.token_times.append(now)
-            hit_eos = self.ecfg.eos_id >= 0 and tok == self.ecfg.eos_id
-            # per-request decode budget: a trace-sampled early stop below
-            # the engine ceiling models an eos emission (eos-aware traces)
-            budget = self._budget(req)
-            if hit_eos or len(req.tokens_out) >= budget:
-                req.finish_s = now
-                req.finish_reason = (
-                    "eos" if (hit_eos
-                              or budget < self.ecfg.max_new_tokens)
-                    else "budget")
-                self.completed.append(req)
-                if self.tracer.enabled:
-                    self.tracer.emit("finish", ts=now, slot=slot,
-                                     rid=req.rid,
-                                     reason=req.finish_reason,
-                                     tokens=len(req.tokens_out))
-                del self.active[slot]
-                self._release(slot)
-                finished += 1
-            else:
-                self._next_tok[slot] = tok
-        if self.tracer.enabled:
-            self.tracer.emit("decode_step", ts=t_step0, dur=now - t_step0,
-                             batch=batch0, finished=finished)
+        tr = self.tracer
+        with tr.span("decode_step") as sp:
+            batch0 = len(self.active)
+            self._pre_decode_grow()
+            if tr.enabled:
+                sp.args.update(self._step_pages())
+            toks = jnp.asarray(self._next_tok)
+            logits = self._decode_batch(toks)
+            # argmax on device, one host fetch per step — dispatch stays
+            # async until the sampled ids are actually needed
+            nxt = jnp.argmax(logits[:, : self.cfg.vocab], axis=-1)
+            with sp.wait():
+                nxt = np.asarray(nxt, np.int32)
+            now = time.perf_counter()
+            finished = 0
+            for slot, req in list(self.active.items()):
+                tok = int(nxt[slot])
+                req.tokens_out.append(tok)
+                req.token_times.append(now)
+                hit_eos = self.ecfg.eos_id >= 0 and tok == self.ecfg.eos_id
+                # per-request decode budget: a trace-sampled early stop
+                # below the engine ceiling models an eos emission
+                # (eos-aware traces)
+                budget = self._budget(req)
+                if hit_eos or len(req.tokens_out) >= budget:
+                    req.finish_s = now
+                    req.finish_reason = (
+                        "eos" if (hit_eos
+                                  or budget < self.ecfg.max_new_tokens)
+                        else "budget")
+                    self.completed.append(req)
+                    if tr.enabled:
+                        tr.emit("finish", ts=now, slot=slot, rid=req.rid,
+                                reason=req.finish_reason,
+                                tokens=len(req.tokens_out))
+                    del self.active[slot]
+                    self._release(slot)
+                    finished += 1
+                else:
+                    self._next_tok[slot] = tok
+            if tr.enabled:
+                sp.args.update(batch=batch0, finished=finished)
         return finished
+
+    def _step_pages(self) -> dict:
+        """Hook (tracing only): the paged decode attention's grid and live
+        pages for the step about to run ({}: the dense engine has no
+        pages)."""
+        return {}
 
     # -- Sarathi chunk scheduler ---------------------------------------
     def _chunkable(self) -> bool:
@@ -385,39 +400,43 @@ class ServingEngine:
         if st is None:
             return False
         tr = self.tracer
-        t_ck0 = time.perf_counter() if tr.enabled else 0.0
-        req, chunk = st["req"], self.ecfg.prefill_chunk
+        req, chunk, slot = st["req"], self.ecfg.prefill_chunk, st["slot"]
         n = len(req.prompt)
-        take = min(chunk, n - st["pos"])
-        toks = jnp.asarray(req.prompt[None, st["pos"]: st["pos"] + take])
-        logits, st["buf"] = self._extend(self.params, toks, st["buf"])
-        # no sync: chunks chain on device; only the final chunk's argmax
-        # (below) fetches a value at the prefill boundary
-        st["pos"] += take
-        st["logits"] = logits
-        if st["pos"] < n:
+        with tr.span("prefill_chunk") as sp:
+            take = min(chunk, n - st["pos"])
+            toks = jnp.asarray(req.prompt[None, st["pos"]: st["pos"] + take])
+            logits, st["buf"] = self._extend(self.params, toks, st["buf"])
+            # no sync: chunks chain on device; only the final chunk's
+            # argmax (below) fetches a value at the prefill boundary
+            st["pos"] += take
+            st["logits"] = logits
+            last = st["pos"] >= n
+            if last:
+                # prompt fully consumed: move the buffer into the slot
+                self._insert(slot, st["buf"], n)
+                self._first_token(sp, st)
             if tr.enabled:
-                tr.emit("prefill_chunk", ts=t_ck0,
-                        dur=time.perf_counter() - t_ck0, slot=st["slot"],
-                        rid=req.rid, tokens=take, pos=st["pos"],
-                        last=False)
+                sp.args.update(slot=slot, rid=req.rid, tokens=take,
+                               pos=st["pos"], last=last)
+        if not last:
             return False
-        # prompt fully consumed: move the buffer into the slot
-        slot = st["slot"]
-        self._insert(slot, st["buf"], n)
-        first = int(jnp.argmax(st["logits"][0, : self.cfg.vocab]))
+        self._activate(slot, req)
+        self._prefilling = None
+        return True
+
+    def _first_token(self, sp, st: dict) -> None:
+        """The last prefill chunk's argmax, the one fetch of a chunked
+        prefill (it blocks inside the span ``sp``), becomes the request's
+        first token."""
+        req, slot = st["req"], st["slot"]
+        first = jnp.argmax(st["logits"][0, : self.cfg.vocab])
+        with sp.wait():
+            first = int(first)
         self._next_tok[slot] = first
         req.slot = slot
         req.prefill_done_s = time.perf_counter() - st["t0"]
         req.first_token_s = time.perf_counter()
         req.tokens_out.append(first)
-        if tr.enabled:
-            tr.emit("prefill_chunk", ts=t_ck0,
-                    dur=req.first_token_s - t_ck0, slot=slot, rid=req.rid,
-                    tokens=take, pos=n, last=True)
-        self._activate(slot, req)
-        self._prefilling = None
-        return True
 
     # -- narrow replica interface (driven by Scheduler / Router) -------
     def admit(self, req: RequestState) -> bool:
@@ -626,13 +645,10 @@ class PagedServingEngine(ServingEngine):
         self._region_peak: Dict[int, int] = {}
         self._paged_decode = None   # built lazily (pallas path)
         # fused multi-step decode (lax.scan engine core): one jitted
-        # callable per bucketed horizon length, plus host/device wall
-        # split for the host-overhead metric
+        # callable per bucketed horizon length
         self._fused_jits: Dict[int, Any] = {}
         self._fused_ticks = 0
         self._fused_steps_sum = 0
-        self._fused_host_s = 0.0
-        self._fused_device_s = 0.0
         # realized horizons (obs histogram source) and the constraint
         # that clamped the most recent one (fused_tick trace events)
         self.fused_horizons: List[int] = []
@@ -810,39 +826,31 @@ class PagedServingEngine(ServingEngine):
         if st is None or not st.get("direct"):
             return super()._prefill_chunk_tick()
         tr = self.tracer
-        t_ck0 = time.perf_counter() if tr.enabled else 0.0
         req, chunk, slot = st["req"], self.ecfg.prefill_chunk, st["slot"]
         n = len(req.prompt)
-        take = min(chunk, n - st["pos"])
-        toks = jnp.asarray(req.prompt[None, st["pos"]: st["pos"] + take])
-        view = self.paged.gather_slot(slot, st["pos"])
-        logits, view = self._extend(self.params, toks, view)
-        # no sync: the scatter chains on the extend on device; only the
-        # final chunk's argmax (below) fetches a value
-        self.paged.scatter_chunk(slot, view, st["pos"], take)
-        st["pos"] += take
-        st["logits"] = logits
-        if st["pos"] < n:
+        with tr.span("prefill_chunk") as sp:
+            take = min(chunk, n - st["pos"])
+            toks = jnp.asarray(req.prompt[None, st["pos"]: st["pos"] + take])
+            view = self.paged.gather_slot(slot, st["pos"])
+            logits, view = self._extend(self.params, toks, view)
+            # no sync: the scatter chains on the extend on device; only
+            # the final chunk's argmax (below) fetches a value
+            self.paged.scatter_chunk(slot, view, st["pos"], take)
+            st["pos"] += take
+            st["logits"] = logits
+            last = st["pos"] >= n
+            if last:
+                # prompt fully consumed: publish prefix pages, activate
+                # the slot
+                self.paged.commit_prefix(slot)
+                self._lengths_host[slot] = n
+                self._note_pages()
+                self._first_token(sp, st)
             if tr.enabled:
-                tr.emit("prefill_chunk", ts=t_ck0,
-                        dur=time.perf_counter() - t_ck0, slot=slot,
-                        rid=req.rid, tokens=take, pos=st["pos"],
-                        last=False)
+                sp.args.update(slot=slot, rid=req.rid, tokens=take,
+                               pos=st["pos"], last=last)
+        if not last:
             return False
-        # prompt fully consumed: publish prefix pages, activate the slot
-        self.paged.commit_prefix(slot)
-        self._lengths_host[slot] = n
-        self._note_pages()
-        first = int(jnp.argmax(st["logits"][0, : self.cfg.vocab]))
-        self._next_tok[slot] = first
-        req.slot = slot
-        req.prefill_done_s = time.perf_counter() - st["t0"]
-        req.first_token_s = time.perf_counter()
-        req.tokens_out.append(first)
-        if tr.enabled:
-            tr.emit("prefill_chunk", ts=t_ck0,
-                    dur=req.first_token_s - t_ck0, slot=slot, rid=req.rid,
-                    tokens=take, pos=n, last=True)
         self._activate(slot, req)
         self._prefilling = None
         return True
@@ -965,14 +973,14 @@ class PagedServingEngine(ServingEngine):
         from repro.kernels import ops as kops
         mod, cfg, tp = self.entry.module, self.cfg, self.tp
         if self._paged_decode is None:
-            attn_fn = (lambda q, kc, vc, t, ln:
-                       kops.attention_decode_paged(q, kc, vc, t, ln))
-            self._paged_decode = jax.jit(
-                lambda params, tokens, kp, vp, tables, lengths:
-                mod.decode_step_paged(params, cfg, tokens, kp, vp,
-                                      tables, lengths, tp=tp,
-                                      attn_fn=attn_fn),
-                donate_argnums=(2, 3))
+            def engine_paged_decode(params, tokens, kp, vp, tables,
+                                    lengths):
+                return mod.decode_step_paged(
+                    params, cfg, tokens, kp, vp, tables, lengths, tp=tp,
+                    attn_fn=kops.attention_decode_paged)
+
+            self._paged_decode = jax.jit(engine_paged_decode,
+                                         donate_argnums=(2, 3))
         seq_idx = [i for i, s in enumerate(self.paged.is_seq) if s]
         assert len(seq_idx) == 2, "pallas paged decode expects k/v pools"
         ki, vi = seq_idx
@@ -1062,16 +1070,18 @@ class PagedServingEngine(ServingEngine):
             attn_fn = None
             if self.ecfg.use_pallas_decode:
                 from repro.kernels import ops as kops
-                attn_fn = (lambda q, kc, vc, t, ln:
-                           kops.attention_decode_paged(q, kc, vc, t, ln))
+                attn_fn = kops.attention_decode_paged
             eos = self.ecfg.eos_id
-            fn = jax.jit(
-                lambda params, toks, kp, vp, tables, lengths, alive, ka:
-                mod.decode_fused_paged(params, cfg, toks, kp, vp, tables,
-                                       lengths, alive, ka, n_steps,
-                                       tp=tp, attn_fn=attn_fn,
-                                       eos_id=eos),
-                donate_argnums=(2, 3))
+
+            def engine_fused_decode(params, toks, kp, vp, tables, lengths,
+                                    alive, ka):
+                return mod.decode_fused_paged(
+                    params, cfg, toks, kp, vp, tables, lengths, alive, ka,
+                    n_steps, tp=tp, attn_fn=attn_fn, eos_id=eos)
+
+            # one XLA module per bucketed horizon, named by it
+            engine_fused_decode.__name__ = f"engine_fused_decode_{n_steps}"
+            fn = jax.jit(engine_fused_decode, donate_argnums=(2, 3))
             self._fused_jits[n_steps] = fn
         return fn
 
@@ -1083,8 +1093,8 @@ class PagedServingEngine(ServingEngine):
         then ONE device dispatch and ONE fetch for all K tokens, then
         finish bookkeeping.  Falls back to the per-step path when the
         horizon degenerates to a single step."""
-        t_tick0 = time.perf_counter()
         ecfg = self.ecfg
+        tr = self.tracer
         pf_tokens = pf_ctx = 0
         if self._chunkable():
             st = self._prefilling
@@ -1093,86 +1103,140 @@ class PagedServingEngine(ServingEngine):
                                 len(st["req"].prompt) - st["pos"])
                 pf_ctx = st["pos"] + pf_tokens
             self._prefill_chunk_tick()
-        t_dec0 = time.perf_counter() if self.tracer.enabled else 0.0
         if not self.active:
             self._note_tick(0, [], pf_tokens, pf_ctx)
-            if self.tracer.enabled:
+            if tr.enabled:
                 self._trace_gauges()
             return 0
-        self._pre_decode_grow()
-        k = self._fused_horizon()
+        # the span starts after the co-scheduled prefill chunk so
+        # prefill/decode phases stay disjoint in trace_report
+        with tr.span("fused_tick") as sp:
+            with sp.phase("prepare"):
+                self._pre_decode_grow()
+                k = self._fused_horizon()
+                if k > 1:
+                    inputs = self._fused_inputs(k)
+            if k > 1:
+                finished = self._fused_run(sp, k, inputs, pf_tokens,
+                                           pf_ctx)
+            else:
+                sp.discard()       # the per-step path below has its own
         if k <= 1:
             if self._tick_model is not None:
                 ctxs = [len(r.prompt) + len(r.tokens_out)
                         for r in self.active.values()]
                 self._note_tick(len(ctxs), ctxs, pf_tokens, pf_ctx)
-            n_fin = self.step()
-            if self.tracer.enabled:
-                self._trace_gauges()
-            return n_fin
+            finished = self.step()
+        if tr.enabled:
+            self._trace_gauges()
+        return finished
+
+    def _fused_inputs(self, k: int) -> dict:
+        """Fork what the next ``k`` writes touch, then upload the fused
+        scan's inputs: tokens, lengths, the live-lane mask and the
+        masked block tables."""
+        ecfg = self.ecfg
         self._cow_horizon(k)
         self._note_gather_cost()     # one placement sample per fused tick
-        base_ctx = {s: len(r.prompt) + len(r.tokens_out)
-                    for s, r in self.active.items()}
         active = np.zeros((ecfg.max_batch,), bool)
         for s in self.active:
             active[s] = True
         act_dev = jnp.asarray(active)
-        lengths = jnp.asarray(np.where(active, self._lengths_host, 0),
-                              jnp.int32)
-        toks = jnp.asarray(self._next_tok)
         # inactive lanes (mid chunked-prefill slots can hold live shared
         # pages) route to the scratch page, on device, over the mirror
         tables = jnp.where(act_dev[:, None], self.paged.tables_device(),
                            self.paged.num_pages)
+        return {"base_ctx": {s: len(r.prompt) + len(r.tokens_out)
+                             for s, r in self.active.items()},
+                "act_dev": act_dev, "tables": tables,
+                "lengths": jnp.asarray(np.where(active, self._lengths_host,
+                                                0), jnp.int32),
+                "toks": jnp.asarray(self._next_tok)}
+
+    def _fused_run(self, sp, k: int, inputs: dict, pf_tokens: int,
+                   pf_ctx: int) -> int:
+        """Dispatch the fused scan, fetch its tokens, apply them; returns
+        #finished.  ``sp`` is the tick's span."""
+        tr = self.tracer
         seq_idx = [i for i, s in enumerate(self.paged.is_seq) if s]
         assert len(seq_idx) == 2, "fused decode expects k/v pools"
         ki, vi = seq_idx
         store = list(self.paged.store)
+        act_dev = inputs["act_dev"]
         # bucket the scan length to a power of two: a handful of compiled
         # horizons serve every K, and lanes freeze at idx >= k on device
         n_steps = 1 << (k - 1).bit_length()
-        fn = self._fused_fn(n_steps)
-        t_dev0 = time.perf_counter()
-        tok_seq, emit_seq, kp, vp, new_len = fn(
-            self.params, toks, store[ki], store[vi], tables, lengths,
-            act_dev, jnp.asarray(k, jnp.int32))
-        tok_h = np.asarray(tok_seq)      # the single per-horizon fetch
-        emit_h = np.asarray(emit_seq)
+        with sp.phase("dispatch"):
+            fn = self._fused_fn(n_steps)
+            t_dev0 = time.perf_counter()
+            tok_seq, emit_seq, kp, vp, new_len = fn(
+                self.params, inputs["toks"], store[ki], store[vi],
+                inputs["tables"], inputs["lengths"], act_dev,
+                jnp.asarray(k, jnp.int32))
+        with sp.wait():
+            tok_h = np.asarray(tok_seq)      # the single per-horizon fetch
+            emit_h = np.asarray(emit_seq)
         t_dev1 = time.perf_counter()
-        store[ki], store[vi] = kp, vp
-        li = [i for i, s in enumerate(self.paged.is_seq)
-              if not s and store[i].ndim == 1]
-        assert len(li) == 1
-        store[li[0]] = jnp.where(act_dev, new_len, store[li[0]])
-        self.paged.store = store
-        finished = self._apply_fused(tok_h, emit_h, k, t_dev0, t_dev1)
-        if self._tick_model is not None:
-            # post-hoc per-step attribution: step j's batch is the lanes
-            # that actually ran it (eos'd lanes drop out mid-horizon, as
-            # they would tick-by-tick); the prefill chunk rides step 0
-            for j in range(k):
-                ctxs = [base_ctx[s] + j for s in base_ctx
-                        if emit_h[j, s]]
-                self._note_tick(len(ctxs), ctxs,
-                                pf_tokens if j == 0 else 0,
-                                pf_ctx if j == 0 else 0)
-        t_tick1 = time.perf_counter()
-        self._fused_ticks += 1
-        self._fused_steps_sum += k
-        self.fused_horizons.append(k)
-        dev = t_dev1 - t_dev0
-        self._fused_device_s += dev
-        self._fused_host_s += (t_tick1 - t_tick0) - dev
-        if self.tracer.enabled:
-            # the span starts after the co-scheduled prefill chunk so
-            # prefill/decode phases stay disjoint in trace_report
-            self.tracer.emit("fused_tick", ts=t_dec0,
-                             dur=t_tick1 - t_dec0, batch=len(base_ctx),
-                             horizon=k, clamp=self._last_horizon_clamp,
-                             device_s=dev, finished=finished)
-            self._trace_gauges()
+        with sp.phase("apply"):
+            if tr.enabled:
+                # from the lengths before the tick, so ahead of the apply
+                sp.args.update(self._grid_counts(self._lengths_host,
+                                                 emit_h))
+            store[ki], store[vi] = kp, vp
+            li = [i for i, s in enumerate(self.paged.is_seq)
+                  if not s and store[i].ndim == 1]
+            assert len(li) == 1
+            store[li[0]] = jnp.where(act_dev, new_len, store[li[0]])
+            self.paged.store = store
+            finished = self._apply_fused(tok_h, emit_h, k, t_dev0, t_dev1)
+            if self._tick_model is not None:
+                # post-hoc per-step attribution: step j's batch is the
+                # lanes that actually ran it (eos'd lanes drop out
+                # mid-horizon, as they would tick-by-tick); the prefill
+                # chunk rides step 0
+                base_ctx = inputs["base_ctx"]
+                for j in range(k):
+                    ctxs = [base_ctx[s] + j for s in base_ctx
+                            if emit_h[j, s]]
+                    self._note_tick(len(ctxs), ctxs,
+                                    pf_tokens if j == 0 else 0,
+                                    pf_ctx if j == 0 else 0)
+            self._fused_ticks += 1
+            self._fused_steps_sum += k
+            self.fused_horizons.append(k)
+        if tr.enabled:
+            sp.args.update(batch=len(inputs["base_ctx"]), horizon=k,
+                           clamp=self._last_horizon_clamp,
+                           finished=finished)
         return finished
+
+    def _step_pages(self) -> dict:
+        if not self.paged.has_seq:
+            return {}
+        active = np.zeros((1, self.ecfg.max_batch), bool)
+        active[0, list(self.active)] = True
+        return self._grid_counts(self._lengths_host, active)
+
+    def _grid_counts(self, lengths: np.ndarray, emitted: np.ndarray
+                     ) -> dict:
+        """The paged decode attention's pages over one scan of
+        ``len(emitted)`` steps (tracing only), in lane-page units per
+        layer per KV head: ``grid_pages``, the kernel's whole grid at
+        every step the device ran (frozen steps run it too; the
+        reference gather walks the same pages), and ``live_pages``, the
+        pages that held context, ceil((lengths[b] + j + 1) / page_size)
+        for each lane b that emitted at step j."""
+        from repro.kernels.flash_decode import paged_grid
+        n_steps, ps = len(emitted), self.ecfg.page_size
+        ki = self.paged.is_seq.index(True)
+        hq, _ = self.cfg.padded_heads(self.tp)
+        b, _, nblk = paged_grid((self.ecfg.max_batch, hq, self.cfg.d_head),
+                                self.paged.store[ki].shape[1:],
+                                self.paged.tables.shape)
+        ctx = lengths[None, :] + np.arange(1, n_steps + 1)[:, None]
+        live = np.where(emitted, -(-ctx // ps), 0)
+        return {"grid_pages": n_steps * b * nblk,
+                "live_pages": int(live.sum())}
 
     def _trace_gauges(self) -> None:
         args = {"active": len(self.active),
@@ -1236,11 +1300,9 @@ class PagedServingEngine(ServingEngine):
         """Fused-tick accounting ({} before any fused tick ran)."""
         if not self._fused_ticks:
             return {}
-        tot = self._fused_host_s + self._fused_device_s
         return {"fused_ticks": self._fused_ticks,
                 "fused_steps_mean": (self._fused_steps_sum
-                                     / self._fused_ticks),
-                "host_frac": self._fused_host_s / tot if tot > 0 else 0.0}
+                                     / self._fused_ticks)}
 
     def reset_fused_counters(self) -> None:
         """Zero the fused-tick accounting — warmup-then-measure drivers
@@ -1248,8 +1310,6 @@ class PagedServingEngine(ServingEngine):
         report covers only the measured region."""
         self._fused_ticks = 0
         self._fused_steps_sum = 0
-        self._fused_host_s = 0.0
-        self._fused_device_s = 0.0
         self.fused_horizons = []
 
 

@@ -1096,27 +1096,29 @@ class PagedCache:
                 "defrag target crossed a placement region"
         if all(o == n for o, n in mapping.items()):
             return mapping
-        perm = np.arange(self.num_pages + 1)
-        for old, new in mapping.items():
-            perm[new] = old
-        perm_dev = jnp.asarray(perm, jnp.int32)
-        self.store = [
-            _permute_pool(pool, perm_dev) if seq else pool
-            for pool, seq in zip(self.store, self.is_seq)]
-        lut = np.full(self.num_pages + 1, -1, np.int32)
-        for old, new in mapping.items():
-            lut[old] = new
-        self.tables = np.where(self.tables < 0, -1,
-                               lut[np.maximum(self.tables, 0)]
-                               ).astype(np.int32)
-        self.alloc.rebuild({mapping[p]: self.alloc.refcount(p)
-                            for p in live})
-        moved_n = sum(1 for o, n in mapping.items() if o != n)
-        cost = self.transfer_pages(moved_n, sys=sys, hops=0,
-                                   kind="defrag")
-        if self.tracer.enabled:
-            self.tracer.emit("defrag", live_pages=len(live),
-                             moved=moved_n, cost_s=cost.time_s)
+        with self.tracer.span("defrag") as sp:
+            perm = np.arange(self.num_pages + 1)
+            for old, new in mapping.items():
+                perm[new] = old
+            perm_dev = jnp.asarray(perm, jnp.int32)
+            # the largest copy the host issues: a whole new pool
+            self.store = [
+                _permute_pool(pool, perm_dev) if seq else pool
+                for pool, seq in zip(self.store, self.is_seq)]
+            lut = np.full(self.num_pages + 1, -1, np.int32)
+            for old, new in mapping.items():
+                lut[old] = new
+            self.tables = np.where(self.tables < 0, -1,
+                                   lut[np.maximum(self.tables, 0)]
+                                   ).astype(np.int32)
+            self.alloc.rebuild({mapping[p]: self.alloc.refcount(p)
+                                for p in live})
+            moved_n = sum(1 for o, n in mapping.items() if o != n)
+            cost = self.transfer_pages(moved_n, sys=sys, hops=0,
+                                       kind="defrag")
+            if self.tracer.enabled:
+                sp.args.update(live_pages=len(live), moved=moved_n,
+                               cost_s=cost.time_s)
         if self.prefix is not None:
             self.prefix.remap(mapping)
             # region-constrained targets must keep the trie consistent:

@@ -377,7 +377,6 @@ class Scheduler:
                 # fused decode loop (EngineConfig.fuse_steps > 1 engines)
                 "fused_ticks": fr.get("fused_ticks", 0),
                 "fused_steps_mean": fr.get("fused_steps_mean", 0.0),
-                "fused_host_frac": fr.get("host_frac", 0.0),
                 # bucketed distribution summaries (live path only — the
                 # analytic mirrors report scalar stats, not samples)
                 "hists": reg.summaries()["histograms"]}

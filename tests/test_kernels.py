@@ -105,6 +105,37 @@ def test_flash_decode_ragged_lengths():
                                rtol=2e-5, atol=2e-5)
 
 
+def test_paged_grid_is_the_grid_the_paged_kernel_runs(monkeypatch):
+    """``paged_grid`` (which the engine's grid counters use) is the grid
+    that ``paged_flash_decode`` hands to Pallas: one program per request,
+    KV head and block-table entry, whatever the lengths."""
+    from repro.kernels import flash_decode as FD
+    b, hq, hkv, d, page, nblk, pages = 3, 8, 2, 64, 16, 5, 12
+    key = jax.random.PRNGKey(3)
+    q = jax.random.normal(key, (b, hq, d), jnp.float32)
+    pool = jax.random.normal(jax.random.fold_in(key, 1),
+                             (pages + 1, page, hkv, d), jnp.float32)
+    tables = jnp.asarray(np.arange(b * nblk).reshape(b, nblk) % pages,
+                         jnp.int32)
+    lengths = jnp.asarray([1, 40, nblk * page], jnp.int32)
+    grids = []
+    real = FD.pl.pallas_call
+
+    def spy(*args, grid_spec, **kw):
+        grids.append(tuple(grid_spec.grid))
+        return real(*args, grid_spec=grid_spec, **kw)
+    monkeypatch.setattr(FD.pl, "pallas_call", spy)
+    out = FD.paged_flash_decode(q, pool, pool, tables, lengths,
+                                interpret=True)
+    assert grids == [(b, hkv, nblk)]
+    assert FD.paged_grid(q.shape, pool.shape, tables.shape) == (b, hkv, nblk)
+    want = ref.flash_decode_ref(
+        q, pool[tables].reshape(b, nblk * page, hkv, d),
+        pool[tables].reshape(b, nblk * page, hkv, d), lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 WKV_SHAPES = [(1, 16, 2, 32), (2, 33, 4, 64), (1, 8, 1, 128)]
 
 

@@ -221,3 +221,156 @@ def test_engine_tokens_bit_identical_tracer_on_off():
     assert rep["finished"] == len(base_t)
     total = sum(rep["phases"].values())
     assert abs(total - rep["makespan_s"]) <= 1e-9 * max(1.0, total)
+
+
+# ---------------------------------------------------------------------------
+# engine spans on the profiler's clock; named programs; grid counters
+# ---------------------------------------------------------------------------
+def _small_engine(**over):
+    from repro.serving.engine import EngineConfig, make_engine
+    base = dict(max_batch=2, max_seq=64, max_new_tokens=6, paged=True,
+                page_size=8, prefill_chunk=8, fuse_steps=4)
+    base.update(over)
+    return make_engine(registry.get("yi-6b", reduced=True),
+                       EngineConfig(**base))
+
+
+def _serve(eng, prompts, rid0=0):
+    from repro.serving.engine import RequestState
+    for i, n in enumerate(prompts):
+        eng.requeue.append(RequestState(rid0 + i,
+                                        np.arange(1, n + 1, dtype=np.int32)))
+    while eng.requeue or eng.busy():
+        if eng.requeue and eng.admit(eng.requeue[0]):
+            eng.requeue.pop(0)
+        eng.tick()
+
+
+def test_engine_spans_nest_in_the_window_on_the_profilers_host_line(
+        tmp_path):
+    """A traced tick's spans are profiler annotations on the thread that
+    holds the enclosing window annotation, inside it, with each child
+    inside its parent; their events carry wait_s <= dur."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+    eng = _small_engine()
+    _serve(eng, [11])                           # compiles every program
+    tr = Tracer()
+    eng.set_tracer(tr)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    with jax.profiler.TraceAnnotation("bench.window"):
+        _serve(eng, [11], rid0=10)
+    jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    host = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                       for e in line.events]
+                if any(n == "bench.window" for n, _, _ in evs):
+                    host = evs
+    by = {}
+    for n, s, e in host:
+        by.setdefault(n, []).append((s, e))
+    (lo, hi), = by["bench.window"]
+    for name in ("serve.prefill_chunk", "serve.prefill_chunk.wait",
+                 "serve.fused_tick", "serve.fused_tick.prepare",
+                 "serve.fused_tick.dispatch", "serve.fused_tick.wait",
+                 "serve.fused_tick.apply"):
+        assert by.get(name), f"{name} is not on the window's host line"
+        assert all(lo <= s <= e <= hi for s, e in by[name]), name
+        parent = name.rsplit(".", 1)[0]
+        if parent != "serve":
+            assert all(any(ps <= s and e <= pe for ps, pe in by[parent])
+                       for s, e in by[name]), f"{name} outside {parent}"
+    kinds = [e.kind for e in tr.events]
+    assert kinds.count("prefill_chunk") == 2 and "fused_tick" in kinds
+    waits = [e for e in tr.events if "wait_s" in e.args]
+    assert {e.kind for e in waits} == {"prefill_chunk", "fused_tick",
+                                       "decode_step"}
+    assert all(0.0 <= e.args["wait_s"] <= e.dur for e in waits)
+    assert not any("device_s" in e.args for e in tr.events)
+
+
+def test_untraced_engine_opens_no_annotation_and_counts_nothing(
+        monkeypatch):
+    import jax
+
+    from repro.serving.engine import PagedServingEngine
+
+    def boom(*a, **k):
+        raise AssertionError("the untraced engine did tracing work")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    monkeypatch.setattr(PagedServingEngine, "_grid_counts", boom)
+    monkeypatch.setattr(PagedServingEngine, "_step_pages", boom)
+    eng = _small_engine()
+    assert eng.tracer is NULL_TRACER
+    _serve(eng, [11, 5])
+    # both decode paths ran: fused ticks and a one-step tick
+    assert eng.fused_horizons and len(eng.completed) == 2
+    assert NULL_TRACER.span("fused_tick") is NULL_TRACER.span("defrag")
+
+
+def test_engine_programs_are_named():
+    """Every program the engine jits lowers to a module jit_engine_*."""
+    import jax.numpy as jnp
+
+    def module(fn, *args):
+        line = next(ln for ln in fn.lower(*args).as_text().splitlines()
+                    if ln.startswith("module @"))
+        return line.split()[1]
+
+    eng = _small_engine()
+    _serve(eng, [11])
+    pc = eng.paged
+    ki, vi = [i for i, s in enumerate(pc.is_seq) if s]
+    lanes = jnp.zeros((2,), jnp.int32)
+    assert module(eng._extend, eng.params, jnp.zeros((1, 8), jnp.int32),
+                  pc.gather_slot(0, 0)) == "@jit_engine_extend"
+    for n in eng._fused_jits:
+        assert module(eng._fused_fn(n), eng.params, lanes, pc.store[ki],
+                      pc.store[vi], pc.tables_device(), lanes,
+                      jnp.ones((2,), bool), jnp.asarray(2, jnp.int32)
+                      ) == f"@jit_engine_fused_decode_{n}"
+    assert eng._fused_jits
+    paged = _small_engine(fuse_steps=1, prefill_chunk=None,
+                          use_pallas_decode=True, max_new_tokens=2)
+    _serve(paged, [5])
+    assert module(paged._paged_decode, paged.params, lanes,
+                  paged.paged.store[ki], paged.paged.store[vi],
+                  paged.paged.tables_device(), lanes
+                  ) == "@jit_engine_paged_decode"
+    dense = _small_engine(paged=False, prefill_chunk=None, fuse_steps=1)
+    toks = jnp.zeros((1, 5), jnp.int32)
+    assert module(dense._prefill, dense.params, toks) == \
+        "@jit_engine_prefill"
+    assert module(dense._decode, dense.params, dense.cache, lanes) == \
+        "@jit_engine_decode"
+
+
+def test_grid_counts_match_a_hand_count():
+    """Prompts of 11 and 5 tokens, pages of 8, a 64-token table (8
+    blocks), 2 lanes.  The first fused tick stops at lane 1's page edge
+    after 3 steps and runs the 4-step bucket; lane 0 attends 12-14
+    tokens (2 pages each step), lane 1 6-8 (1 page).  The second runs 2
+    steps, both lanes at 2 pages."""
+    eng = _small_engine(prefill_chunk=None, fuse_steps=8)
+    tr = Tracer()
+    eng.set_tracer(tr)
+    from repro.serving.engine import RequestState
+    for rid, n in enumerate((11, 5)):
+        assert eng.submit(RequestState(rid, np.arange(1, n + 1,
+                                                      dtype=np.int32)))
+    while eng.busy():
+        eng.tick()
+    ticks = [e.args for e in tr.events if e.kind == "fused_tick"]
+    assert [(t["horizon"], t["grid_pages"], t["live_pages"])
+            for t in ticks] == [(3, 4 * 2 * 8, 3 * 2 + 3 * 1),
+                                (2, 2 * 2 * 8, 2 * 2 + 2 * 2)]
