@@ -1,9 +1,12 @@
 """CPU tests of the benchmark's yardstick: traffic, end-to-end
 arithmetic and FLOP/byte counts.  No accelerator is needed or loaded."""
+import json
+import os
+
 import numpy as np
 import pytest
 
-from bench import e2e, flops, traffic
+from bench import e2e, flops, peaks, spec, traffic
 
 CHAT = {"arrival": {"kind": "poisson", "rate_req_s": 2.0}, "ramp_s": 5.0,
         "shared_prefix": {"tokens": 64, "count": 1},
@@ -226,3 +229,48 @@ def test_kv_bytes_and_attention_flops(s, kv_bytes):
     assert flops.attn_flops(s, 1000) == 4 * 32 * s.head_dim * 1000 * 32
     assert flops.decode_flops(s, 3, 1000) == \
         3 * flops.matmul_flops_per_token(s) + flops.attn_flops(s, 1000)
+
+
+# the chip every cell runs on; its peaks bound how fast any program
+# could drain a backlog
+CHIP = "TPU v5 lite"
+with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
+    BENCHMARK = json.load(f)
+BACKLOG_CELLS = [w["name"] for w in BENCHMARK["workloads"]
+                 if spec.load_cell(w["name"]).traffic["arrival"]["kind"]
+                 == "backlog"]
+
+
+def roofline_finished(mix: dict, s: flops.Shapes, max_batch: int,
+                      peak: peaks.Peak, seconds: float) -> int:
+    """Requests of ``mix``, in ``generate``'s order, that a chip at its
+    peaks could finish within ``seconds``: each costs at least its
+    prompt's matmul FLOPs at the bf16 peak, and one read of the weights
+    at the HBM peak for every ``max_batch`` tokens it decodes after its
+    first."""
+    weight_read_s = flops.matmul_params(s) * s.dtype_bytes / peak.hbm_bytes_s
+    t, done = 0.0, 0
+    for r in traffic.generate(mix, s.vocab, 0, seconds):
+        t += (flops.matmul_flops_per_token(s) * len(r.prompt)
+              / peak.bf16_flops
+              + (r.decode_len - 1) / max_batch * weight_read_s)
+        if t > seconds:
+            break
+        done += 1
+    return done
+
+
+@pytest.mark.parametrize("name", BACKLOG_CELLS)
+def test_backlog_outlasts_the_roofline(name):
+    """A backlog cell measures throughput only while its lanes stay
+    full: it holds at least twice the requests that the chip at its
+    peaks could finish within the ramp and the window, so a faster
+    program still finds work waiting at the close."""
+    cell = spec.load_cell(name)
+    s = flops.Shapes.from_config(cell.config["model"])
+    seconds = cell.traffic["ramp_s"] + BENCHMARK["run_seconds"]
+    could = roofline_finished(cell.traffic, s,
+                              cell.config["engine"]["max_batch"],
+                              peaks.peak_for(CHIP), seconds)
+    assert could > 0
+    assert cell.traffic["arrival"]["requests"] >= 2 * could
