@@ -71,9 +71,10 @@ from typing import Any, Dict, List, Optional
 #:
 #: wait_s is the seconds a span spent blocked in its device fetch;
 #: grid_pages / live_pages count the paged decode attention's pages in
-#: lane-page units (per layer, per KV head): the kernel's whole grid
-#: for every scan step the device ran, and the pages that held context
-#: for the lanes that emitted a token at that step.
+#: lane-page units (per layer, per KV head): the blocks of table entries
+#: the kernel walks for every lane at every scan step the device ran,
+#: and the pages that held context for the lanes that emitted a token at
+#: that step.
 EVENT_KINDS = (
     "arrival", "dispatch", "admit", "prefill_chunk", "decode_step",
     "fused_tick", "grow", "preempt", "cow_fork", "defrag", "migrate",

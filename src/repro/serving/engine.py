@@ -1221,21 +1221,26 @@ class PagedServingEngine(ServingEngine):
                      ) -> dict:
         """The paged decode attention's pages over one scan of
         ``len(emitted)`` steps (tracing only), in lane-page units per
-        layer per KV head: ``grid_pages``, the kernel's whole grid at
-        every step the device ran (frozen steps run it too; the
-        reference gather walks the same pages), and ``live_pages``, the
-        pages that held context, ceil((lengths[b] + j + 1) / page_size)
-        for each lane b that emitted at step j."""
-        from repro.kernels.flash_decode import paged_grid
-        n_steps, ps = len(emitted), self.ecfg.page_size
+        layer per KV head.  ``grid_pages`` is what the kernel walks at
+        every step the device ran: for each lane, the table entries of
+        the context it attends, rounded up to whole blocks of
+        ``pages_per_block`` (a frozen lane attends its frozen context,
+        and a lane outside the batch one scratch page).  ``live_pages``
+        is the pages that held context, ceil((lengths[b] + j + 1) /
+        page_size) for each lane b that emitted at step j."""
+        from repro.kernels.flash_decode import pages_per_block
+        ps = self.ecfg.page_size
         ki = self.paged.is_seq.index(True)
-        hq, _ = self.cfg.padded_heads(self.tp)
-        b, _, nblk = paged_grid((self.ecfg.max_batch, hq, self.cfg.d_head),
-                                self.paged.store[ki].shape[1:],
-                                self.paged.tables.shape)
-        ctx = lengths[None, :] + np.arange(1, n_steps + 1)[:, None]
+        pool = self.paged.store[ki]
+        nblk = self.paged.tables.shape[1]
+        ppb = pages_per_block(pool.shape[1:], pool.dtype, nblk)
+        # the kernel sees lengths + 1; lanes outside the batch get 0, and
+        # every lane in it runs step 0
+        base = np.where(emitted[0], lengths, 0)
+        ctx = base + np.cumsum(emitted, axis=0) - emitted + 1
+        walk = np.minimum(-(-ctx // ps), nblk)
         live = np.where(emitted, -(-ctx // ps), 0)
-        return {"grid_pages": n_steps * b * nblk,
+        return {"grid_pages": int((-(-walk // ppb) * ppb).sum()),
                 "live_pages": int(live.sum())}
 
     def _trace_gauges(self) -> None:

@@ -1,5 +1,6 @@
 """The serving path's kernels and its fused paged decode step, compiled
-for a described TPU v5e chip at Yi-6B widths.
+for a described TPU v5e chip at Yi-6B widths and at the shapes of the
+StableLM-3B long-document benchmark cell.
 
 No chip is needed: the TPU compiler runs on the host and refuses here what
 the chip would refuse (untiled blocks, VMEM overflow, programs that do not
@@ -7,6 +8,8 @@ fit in HBM).  Interpret mode, which the other kernel tests use, sees none
 of that.  The topology is described inside a fixture, never at import, so
 that only the worker running this file loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -21,6 +24,9 @@ PAGE = 16              # tokens per page
 PAGES = 1024           # page pool (plus one scratch page)
 NBLK = 37              # block-table width: 592 tokens of context
 HBM_BYTES = 16 * 2**30
+# the StableLM-3B cell (bench/configs/stablelm-3b.json): 4 lanes, 512
+# pages of 16 tokens, a 4,096-token table
+CELL_B, CELL_PAGES, CELL_NBLK = 4, 512, 256
 
 
 @pytest.fixture(scope="module")
@@ -63,13 +69,18 @@ def _check(compiled) -> int:
     return total
 
 
-def test_paged_flash_decode_compiles(one_chip, yi):
-    pool = _spec(one_chip, (PAGES + 1, PAGE, yi.num_kv_heads, yi.d_head))
+@pytest.mark.parametrize("arch,b,pages,nblk", [
+    ("yi-6b", B, PAGES, NBLK),
+    ("stablelm-3b", CELL_B, CELL_PAGES, CELL_NBLK),   # D 80: padded lanes
+])
+def test_paged_flash_decode_compiles(one_chip, arch, b, pages, nblk):
+    cfg = registry.get(arch).config
+    pool = _spec(one_chip, (pages + 1, PAGE, cfg.num_kv_heads, cfg.d_head))
     fn = jax.jit(lambda q, k, v, t, ln: paged_flash_decode(
         q, k, v, t, ln, interpret=False))
-    _check(fn.lower(_spec(one_chip, (B, yi.num_q_heads, yi.d_head)),
-                    pool, pool, _spec(one_chip, (B, NBLK), jnp.int32),
-                    _spec(one_chip, (B,), jnp.int32)).compile())
+    _check(fn.lower(_spec(one_chip, (b, cfg.num_q_heads, cfg.d_head)),
+                    pool, pool, _spec(one_chip, (b, nblk), jnp.int32),
+                    _spec(one_chip, (b,), jnp.int32)).compile())
 
 
 def test_flash_decode_compiles(one_chip, yi):
@@ -81,27 +92,59 @@ def test_flash_decode_compiles(one_chip, yi):
                     _spec(one_chip, (B,), jnp.int32)).compile())
 
 
-def test_fused_paged_decode_step_compiles(one_chip, yi):
-    """All 32 layers, 8 fused steps, donated pools: the program the paged
-    engine dispatches once per tick."""
+def _fused_decode(sharding, cfg, b, pages, nblk):
+    """The 8-step fused paged decode, all layers, donated pools: the
+    program the paged engine dispatches once per tick, compiled."""
     params = jax.tree.map(
-        lambda a: _spec(one_chip, a.shape, a.dtype),
-        jax.eval_shape(lambda k: T.init(k, yi), jax.random.PRNGKey(0)))
-    pool = _spec(one_chip, (yi.num_layers, PAGES + 1, PAGE,
-                            yi.num_kv_heads, yi.d_head))
+        lambda a: _spec(sharding, a.shape, a.dtype),
+        jax.eval_shape(lambda k: T.init(k, cfg), jax.random.PRNGKey(0)))
+    pool = _spec(sharding, (cfg.num_layers, pages + 1, PAGE,
+                            cfg.num_kv_heads, cfg.d_head))
 
     def attn(q, k, v, t, ln):
         return paged_flash_decode(q, k, v, t, ln, interpret=False)
 
     fn = jax.jit(lambda p, toks, kp, vp, t, ln, alive, ka:
-                 T.decode_fused_paged(p, yi, toks, kp, vp, t, ln, alive, ka,
+                 T.decode_fused_paged(p, cfg, toks, kp, vp, t, ln, alive, ka,
                                       8, attn_fn=attn),
                  donate_argnums=(2, 3))
     i32 = jnp.int32
-    total = _check(fn.lower(
-        params, _spec(one_chip, (B,), i32), pool, pool,
-        _spec(one_chip, (B, NBLK), i32), _spec(one_chip, (B,), i32),
-        _spec(one_chip, (B,), jnp.bool_), _spec(one_chip, (), i32)
-    ).compile())
+    return fn.lower(
+        params, _spec(sharding, (b,), i32), pool, pool,
+        _spec(sharding, (b, nblk), i32), _spec(sharding, (b,), i32),
+        _spec(sharding, (b,), jnp.bool_), _spec(sharding, (), i32)
+    ).compile()
+
+
+def test_fused_paged_decode_step_compiles(one_chip, yi):
+    total = _check(_fused_decode(one_chip, yi, B, PAGES, NBLK))
     # the bfloat16 weights alone are 12.1e9 bytes
     assert total > 12e9
+
+
+def test_fused_decode_reads_the_pool_in_its_stored_layout(one_chip):
+    """At the cell's shapes the kernel takes each layer's pool as the
+    layer loop slices it, ``(P+1, page, Hkv, D)``: no layer of the pool
+    is copied or transposed inside the program's loops, nothing has the
+    ``(P+1, Hkv, page, D)`` shape of a head-major copy, and the program
+    needs less scratch than with the per-call head-major copy."""
+    c = registry.get("stablelm-3b").config
+    compiled = _fused_decode(one_chip, c, CELL_B, CELL_PAGES, CELL_NBLK)
+    _check(compiled)
+    layer = f"{CELL_PAGES + 1},{PAGE},{c.num_kv_heads},{c.d_head}"
+    head_major = f"[{CELL_PAGES + 1},{c.num_kv_heads},{PAGE},{c.d_head}]"
+    hlo = compiled.as_text()
+    assert head_major not in hlo
+    computation, moved = None, []
+    for line in hlo.splitlines():
+        if line and not line.startswith(" "):
+            computation = line.split()[0]
+            continue
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* "
+                     r"(copy|transpose)\(", line)
+        if m and computation != "ENTRY" and m.group(2).endswith(layer):
+            moved.append(m.group(1))
+    assert not moved, moved
+    # 5,696,706,048 bytes with the kernel that copied each layer's pool
+    # head-major on every call (the same compile of the same program)
+    assert compiled.memory_analysis().temp_size_in_bytes < 5_696_706_048

@@ -107,10 +107,11 @@ def test_flash_decode_ragged_lengths():
 
 def test_paged_grid_is_the_grid_the_paged_kernel_runs(monkeypatch):
     """``paged_grid`` (which the engine's grid counters use) is the grid
-    that ``paged_flash_decode`` hands to Pallas: one program per request,
-    KV head and block-table entry, whatever the lengths."""
+    that ``paged_flash_decode`` hands to Pallas: one program per request
+    and block of ``pages_per_block`` table entries, whatever the
+    lengths."""
     from repro.kernels import flash_decode as FD
-    b, hq, hkv, d, page, nblk, pages = 3, 8, 2, 64, 16, 5, 12
+    b, hq, hkv, d, page, nblk, pages = 3, 8, 2, 64, 16, 37, 40
     key = jax.random.PRNGKey(3)
     q = jax.random.normal(key, (b, hq, d), jnp.float32)
     pool = jax.random.normal(jax.random.fold_in(key, 1),
@@ -127,13 +128,73 @@ def test_paged_grid_is_the_grid_the_paged_kernel_runs(monkeypatch):
     monkeypatch.setattr(FD.pl, "pallas_call", spy)
     out = FD.paged_flash_decode(q, pool, pool, tables, lengths,
                                 interpret=True)
-    assert grids == [(b, hkv, nblk)]
-    assert FD.paged_grid(q.shape, pool.shape, tables.shape) == (b, hkv, nblk)
+    # 16 f32 pages of (8-sublane, 128-lane) tiles fill the 2 MiB block
+    ppb = FD.pages_per_block(pool.shape, pool.dtype, nblk)
+    assert ppb == 16
+    assert grids == [(b, 3)]
+    assert FD.paged_grid(q.shape, pool.shape, tables.shape,
+                         pool.dtype) == (b, -(-nblk // ppb))
     want = ref.flash_decode_ref(
         q, pool[tables].reshape(b, nblk * page, hkv, d),
         pool[tables].reshape(b, nblk * page, hkv, d), lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# (hq, hkv, d): StableLM's MHA (G 1, D 80), Yi-6B's groups (G 8, D 128),
+# and G 4 at D 64
+PAGED_SHAPES = [(4, 4, 80), (16, 2, 128), (8, 2, 64)]
+
+
+@pytest.mark.parametrize("hq,hkv,d", PAGED_SHAPES)
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_paged_kernel_reads_only_live_context(hq, hkv, d, dtype):
+    """Lengths at every page and block edge, a length-0 lane on an
+    all-scratch row, permuted tables with a page shared by two lanes, and
+    garbage in dead pages, page tails and the scratch page: the kernel
+    matches the reference on each lane's context and the garbage moves
+    no output bit."""
+    from repro.kernels import flash_decode as FD
+    page, nblk, pages = 16, 35, 64
+    ppb = FD.pages_per_block((pages + 1, page, hkv, d), dtype, nblk)
+    assert nblk > 2 * ppb                         # three blocks, the last
+    lengths = np.array([1, page - 1, page, page + 1,   # partial
+                        ppb * page + 40, nblk * page, 0])
+    b = len(lengths)
+    rng = np.random.default_rng(hq * d)
+    n_live = -(-lengths // page)
+    perm = iter(rng.permutation(pages))
+    tables = np.full((b, nblk), pages)            # unmapped -> scratch
+    for i, n in enumerate(n_live):
+        tables[i, :n] = [next(perm) for _ in range(n)]
+    tables[5, 0] = tables[4, 0]                   # a page held by two lanes
+    # dead entries of one lane point at pages of live context
+    tables[3, n_live[3]:] = rng.integers(0, pages, nblk - n_live[3])
+    key = jax.random.PRNGKey(hq + d)
+    q = jax.random.normal(key, (b, hq, d), jnp.float32).astype(dtype)
+    kp, vp = (jax.random.normal(jax.random.fold_in(key, i),
+                                (pages + 1, page, hkv, d)).astype(dtype)
+              for i in (1, 2))
+    live = np.zeros((pages + 1, page), bool)      # (page id, offset) read
+    for i, n in enumerate(lengths):
+        pos = np.arange(n)
+        live[tables[i, pos // page], pos % page] = True
+    dead = jnp.asarray(~live)[:, :, None, None]
+    tj, lj = jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32)
+    out = FD.paged_flash_decode(q, kp, vp, tj, lj, interpret=True)
+    garbage = FD.paged_flash_decode(
+        q, jnp.where(dead, jnp.asarray(3e4, dtype), kp),
+        jnp.where(dead, jnp.asarray(-7e3, dtype), vp), tj, lj,
+        interpret=True)
+    np.testing.assert_array_equal(np.asarray(garbage), np.asarray(out))
+    want = ref.flash_decode_ref(
+        q, kp[tj].reshape(b, nblk * page, hkv, d),
+        vp[tj].reshape(b, nblk * page, hkv, d), lj)
+    tol = _tol(dtype) if dtype == jnp.bfloat16 else dict(rtol=2e-5,
+                                                         atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out[:-1], np.float32),
+                               np.asarray(want[:-1], np.float32), **tol)
+    assert not np.asarray(out[-1], np.float32).any()   # no context: zeros
 
 
 WKV_SHAPES = [(1, 16, 2, 32), (2, 33, 4, 64), (1, 8, 1, 128)]

@@ -355,12 +355,25 @@ def test_engine_programs_are_named():
         "@jit_engine_decode"
 
 
-def test_grid_counts_match_a_hand_count():
+@pytest.mark.parametrize("ppb,walks", [
+    (8, ((8,) * 8, (8,) * 4)),
+    (3, ((3,) * 8, (3,) * 4)),
+    (1, ((2, 1, 2, 1, 2, 1, 2, 2), (2,) * 4)),
+])
+def test_grid_counts_match_a_hand_count(monkeypatch, ppb, walks):
     """Prompts of 11 and 5 tokens, pages of 8, a 64-token table (8
-    blocks), 2 lanes.  The first fused tick stops at lane 1's page edge
+    entries), 2 lanes.  The first fused tick stops at lane 1's page edge
     after 3 steps and runs the 4-step bucket; lane 0 attends 12-14
-    tokens (2 pages each step), lane 1 6-8 (1 page).  The second runs 2
-    steps, both lanes at 2 pages."""
+    tokens (2 pages each step), lane 1 6-8 (1 page), and in the frozen
+    4th step 15 and 9 (2 pages each).  The second runs 2 steps, both
+    lanes at 2 pages.  The kernel walks each lane's pages in blocks of
+    ``ppb`` table entries (8 at these shapes: the whole table), so a
+    lane-step counts its pages rounded up to whole blocks; the block's
+    bytes are steered here to give 3 and 1."""
+    from repro.kernels import flash_decode as FD
+    # one page of K or V in VMEM: 8 tokens x (4 heads -> 8 f32 sublanes)
+    # x (32 -> 128 lanes) x 4 bytes
+    monkeypatch.setattr(FD, "BLOCK_BYTES", ppb * 2 * 8 * 8 * 128 * 4)
     eng = _small_engine(prefill_chunk=None, fuse_steps=8)
     tr = Tracer()
     eng.set_tracer(tr)
@@ -372,5 +385,5 @@ def test_grid_counts_match_a_hand_count():
         eng.tick()
     ticks = [e.args for e in tr.events if e.kind == "fused_tick"]
     assert [(t["horizon"], t["grid_pages"], t["live_pages"])
-            for t in ticks] == [(3, 4 * 2 * 8, 3 * 2 + 3 * 1),
-                                (2, 2 * 2 * 8, 2 * 2 + 2 * 2)]
+            for t in ticks] == [(3, sum(walks[0]), 3 * 2 + 3 * 1),
+                                (2, sum(walks[1]), 2 * 2 + 2 * 2)]
