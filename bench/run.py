@@ -13,12 +13,14 @@ traffic reaches is warmed; the traffic's schedule runs a warm ramp;
 then the window measures for ``--seconds``.  All of that before the
 window is ``setup_s``.  An executable built or loaded inside the window
 fails the run.  After the window the program's state is freed and the
-reference recomputes a seeded sample of served requests
-(``bench/reference.py``).
+plain reference of the configuration's model module
+(``bench/models/<module>.py``) recomputes a seeded sample of served
+requests (``bench/reference.py``).
 
 ``--control 1`` puts the control in the program's place for the
-check: the reference in float8, read for the token it puts first at
-each position of the same sample, judged by the same limit.  Its run
+check: the reference in the precision below the served one (float8
+for bfloat16), read for the token it puts first at each position of
+the same sample, judged by the same limit.  Its run
 has to come out not correct.  The benchmark's own runs do not use it.
 
 A cell runs one engine on one chip; a cell that asks for more chips
@@ -73,8 +75,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     import jax
     import numpy as np
 
-    from bench import e2e, serve, spec, traffic
-    from bench import reference as REF
+    from bench import e2e, reference, serve, spec, traffic
     from bench import trace as TRC
     if cell.chips != 1:
         raise ValueError(f"{cell.name}: the harness drives one engine on "
@@ -82,7 +83,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
                          f"a cluster driver it does not have")
     cfg = cell.config
     counter = serve.CompileCounter()
-    eng, sch = serve.build(cfg, seed)
+    eng, sch = serve.build(cfg, cell.model, seed)
     t_built = time.perf_counter()
     vocab = cfg["model"]["vocab_size"]
     warm = serve.warm_up(eng, sch, cell.traffic, vocab)
@@ -165,8 +166,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     gc.collect()
     jax.clear_caches()
     t_ref = time.perf_counter()
-    gaps = (REF.served_gaps(REF.Arch.from_config(cfg["model"]), seed, seqs,
-                            control=control) if seqs else
+    gaps = (reference.served_gaps(cell.model.forward, cfg["model"], seed,
+                                  seqs, control=control) if seqs else
             {"served_gap": None, "served_tokens": 0, "control_gap": None})
     log(f"reference over {len(seqs)} requests "
         f"({gaps['served_tokens']} served tokens): "

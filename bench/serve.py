@@ -10,16 +10,16 @@ is when a streaming client would get it.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import jax
 import numpy as np
 
 from bench import traffic as TR
 from bench import weights as W
-from bench.flops import Shapes
 
 WARMUP_RID = 1 << 40      # warm-up request ids start here
 
@@ -44,7 +44,7 @@ class CompileCounter:
 
 
 class ServedModel:
-    """The program's transformer module, with ``init`` left to the
+    """The program's model module, with ``init`` left to the
     benchmark: the engine's own init draws fixed weights, and the
     benchmark serves weights drawn from ``--seed`` instead.  Everything
     else is the program's module, unchanged."""
@@ -64,21 +64,14 @@ def engine_config(cell_cfg: dict):
     return EngineConfig(**cell_cfg["engine"])
 
 
-def arch_config(cell_cfg: dict):
-    """The registry's configuration, checked against the file: the file
-    holds the configuration as it is run, so any difference is an
-    error, not a silent departure."""
+def arch_config(cell_cfg: dict, model):
+    """The registry's configuration, checked against the file through
+    the fields its model module says the file implies: the file holds
+    the configuration as it is run, so any difference is an error, not
+    a silent departure."""
     from repro.models import registry
-    m = cell_cfg["model"]
     cfg = registry.get_config(cell_cfg["registry"])
-    want = {"num_layers": m["num_hidden_layers"], "d_model": m["hidden_size"],
-            "num_q_heads": m["num_attention_heads"],
-            "num_kv_heads": m["num_key_value_heads"],
-            "d_head": m["head_dim"], "d_ff": m["intermediate_size"],
-            "vocab": m["vocab_size"], "rope_theta": m["rope_theta"],
-            "norm": m["norm"], "dtype": m["torch_dtype"],
-            "gated_ffn": True, "act": "silu", "qkv_bias": False,
-            "tie_embeddings": False, "num_experts": 0}
+    want = model.arch_fields(cell_cfg["model"])
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
         raise ValueError(f"registry {cell_cfg['registry']!r} differs from "
@@ -91,8 +84,9 @@ class Tick:
     t0: float
     t1: float
     steps: int                 # decode scan steps the device ran
-    lane_steps: int            # tokens decoded (lanes x steps emitted)
-    attended: int              # cached tokens those tokens attended
+    # per lane that decoded: (tokens decoded, cached tokens before the
+    # first of them), so its i-th token attended ctx + i
+    lanes: List[Tuple[int, int]]
 
 
 @dataclass
@@ -127,7 +121,7 @@ class Client:
     def observe(self, before: Dict[int, int], t0: float, t1: float,
                 horizons_before: int, eng) -> None:
         """Record the tokens a tick delivered, and the decode work it did."""
-        lane_steps = attended = 0
+        lanes = []
         still = []
         for i in self._live:
             st = self.states[i]
@@ -138,9 +132,8 @@ class Client:
                 self.delivered[i] = n
             if n > nb:
                 dec = n - nb - (1 if nb == 0 else 0)   # minus prefill's token
-                ctx = len(st.prompt) + (n - dec) - 1
-                lane_steps += dec
-                attended += dec * ctx + dec * (dec + 1) // 2
+                if dec:
+                    lanes.append((dec, len(st.prompt) + (n - dec) - 1))
             if not (st.done and self.delivered[i] >= self.reqs[i].decode_len):
                 still.append(i)
         self._live = still
@@ -148,8 +141,8 @@ class Client:
             k = eng.fused_horizons[-1]
             steps = 1 << (k - 1).bit_length()
         else:
-            steps = 1 if lane_steps else 0
-        self.ticks.append(Tick(t0, t1, steps, lane_steps, attended))
+            steps = 1 if lanes else 0
+        self.ticks.append(Tick(t0, t1, steps, lanes))
 
 
 def trace_span(on: bool, name: str):
@@ -182,20 +175,17 @@ def drive(sch, client: Client, until: float, annotate: bool = False
             client.observe(before, now, t1, nh, eng)
 
 
-def build(cell_cfg: dict, seed: int):
+def build(cell_cfg: dict, model, seed: int):
     """Engine and scheduler through the program's entry points, serving
-    the benchmark's seeded weights, made on the device in one call."""
+    the seeded weights of the configuration's model module, made on the
+    device in one call."""
     from repro.models import registry
-    from repro.models import transformer
     from repro.serving.engine import make_engine
     from repro.serving.scheduler import Scheduler
-    cfg = arch_config(cell_cfg)
-    entry = registry.ArchEntry(config=cfg, module=ServedModel(transformer))
+    entry = registry.from_config(arch_config(cell_cfg, model))
+    entry = dataclasses.replace(entry, module=ServedModel(entry.module))
     eng = make_engine(entry, engine_config(cell_cfg))
-    shapes = Shapes.from_config(cell_cfg["model"])
-    eng.params = W.model_weights(W.seed_key(seed), shapes,
-                                 cell_cfg["model"]["norm"] == "layernorm",
-                                 jax.numpy.dtype(cfg.dtype))
+    eng.params = model.weights(W.seed_key(seed), cell_cfg["model"])
     jax.block_until_ready(eng.params)
     return eng, Scheduler(eng)
 

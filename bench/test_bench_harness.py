@@ -32,12 +32,13 @@ TINY_CHECK = {"served_gap_limit": 0.006, "min_served_tokens": 100}
 
 
 def tiny_cell(chips=1, **mix_over):
-    cfg = {"registry": "yi-6b", "model": TINY_MODEL, "engine": TINY_ENGINE,
-           "check": TINY_CHECK}
+    cfg = {"registry": "yi-6b", "module": "dense", "model": TINY_MODEL,
+           "engine": TINY_ENGINE, "check": TINY_CHECK}
     metrics = [spec.Metric(n, u) for n, u in
                [("ttft_p50_ms", "ms"), ("tpot_p90_ms", "ms"),
                 ("output_tok_s", "tokens/s"), ("setup_s", "s")]]
     return spec.Cell(name="tiny.chat", chips=chips, config=cfg,
+                     model=spec.model("dense"),
                      traffic={**TINY_MIX, **mix_over},
                      end_to_end=metrics, per_layer=[])
 

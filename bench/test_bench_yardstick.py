@@ -1,12 +1,14 @@
 """CPU tests of the benchmark's yardstick: traffic, end-to-end
-arithmetic and FLOP/byte counts.  No accelerator is needed or loaded."""
+arithmetic, and the backlog against the chip's peaks.  No accelerator
+is needed or loaded.  The dense module's FLOP/byte counts are tested in
+``test_bench_dense.py``."""
 import json
 import os
 
 import numpy as np
 import pytest
 
-from bench import e2e, flops, peaks, spec, traffic
+from bench import e2e, peaks, spec, traffic
 
 CHAT = {"arrival": {"kind": "poisson", "rate_req_s": 2.0}, "ramp_s": 5.0,
         "shared_prefix": {"tokens": 64, "count": 1},
@@ -196,41 +198,6 @@ def test_output_rate_counts_tokens_in_the_window_over_its_length():
     # a token delivered exactly at the open belongs to the tick before
 
 
-YI = flops.Shapes(layers=32, d_model=4096, q_heads=32, kv_heads=4,
-                  head_dim=128, d_ff=11008, vocab=64000)
-STABLELM = flops.Shapes(layers=32, d_model=2560, q_heads=32, kv_heads=32,
-                        head_dim=80, d_ff=6912, vocab=50304)
-
-
-@pytest.mark.parametrize("s, per_layer, head", [
-    # q 4096x4096 + k,v 2x 4096x512 + o 4096x4096 + 3x 4096x11008
-    (YI, 4096 * 4096 + 2 * 4096 * 512 + 4096 * 4096 + 3 * 4096 * 11008,
-     4096 * 64000),
-    # MHA: q,k,v,o 4x 2560x2560 + 3x 2560x6912
-    (STABLELM, 4 * 2560 * 2560 + 3 * 2560 * 6912, 2560 * 50304),
-], ids=["yi-6b", "stablelm-3b"])
-def test_matmul_flops_match_hand_counts(s, per_layer, head):
-    assert flops.matmul_params(s) == 32 * per_layer + head
-    assert flops.matmul_flops_per_token(s) == 2 * (32 * per_layer + head)
-
-
-def test_yi_matmul_params_are_the_published_size_less_the_embedding():
-    # 6.06e9 parameters in all; the embedding table (262M) is a gather
-    assert flops.matmul_params(YI) == 5_798_625_280
-
-
-@pytest.mark.parametrize("s, kv_bytes", [
-    (YI, 2 * 4 * 128 * 2),            # 2 KiB per token per layer
-    (STABLELM, 2 * 32 * 80 * 2),      # 10 KiB per token per layer
-], ids=["yi-6b", "stablelm-3b"])
-def test_kv_bytes_and_attention_flops(s, kv_bytes):
-    assert flops.kv_bytes_per_token_layer(s) == kv_bytes
-    assert flops.paged_attn_bytes(s, 1000) == 1000 * kv_bytes * 32
-    assert flops.attn_flops(s, 1000) == 4 * 32 * s.head_dim * 1000 * 32
-    assert flops.decode_flops(s, 3, 1000) == \
-        3 * flops.matmul_flops_per_token(s) + flops.attn_flops(s, 1000)
-
-
 # the chip every cell runs on; its peaks bound how fast any program
 # could drain a backlog
 CHIP = "TPU v5 lite"
@@ -241,17 +208,21 @@ BACKLOG_CELLS = [w["name"] for w in BENCHMARK["workloads"]
                  == "backlog"]
 
 
-def roofline_finished(mix: dict, s: flops.Shapes, max_batch: int,
-                      peak: peaks.Peak, seconds: float) -> int:
-    """Requests of ``mix``, in ``generate``'s order, that a chip at its
-    peaks could finish within ``seconds``: each costs at least its
-    prompt's matmul FLOPs at the bf16 peak, and one read of the weights
+def roofline_finished(cell: spec.Cell, peak: peaks.Peak,
+                      seconds: float) -> int:
+    """Requests of the cell's mix, in ``generate``'s order, that a chip
+    at its peaks could finish within ``seconds``: each costs at least its
+    prompt's FLOPs at the bf16 peak, and one decode step's weight read
     at the HBM peak for every ``max_batch`` tokens it decodes after its
-    first."""
-    weight_read_s = flops.matmul_params(s) * s.dtype_bytes / peak.hbm_bytes_s
+    first (``prefill_flops_per_token`` and ``weight_bytes_per_step`` of
+    the cell's model module)."""
+    m, model = cell.model, cell.config["model"]
+    max_batch = cell.config["engine"]["max_batch"]
+    weight_read_s = m.weight_bytes_per_step(model, max_batch) \
+        / peak.hbm_bytes_s
     t, done = 0.0, 0
-    for r in traffic.generate(mix, s.vocab, 0, seconds):
-        t += (flops.matmul_flops_per_token(s) * len(r.prompt)
+    for r in traffic.generate(cell.traffic, model["vocab_size"], 0, seconds):
+        t += (m.prefill_flops_per_token(model) * len(r.prompt)
               / peak.bf16_flops
               + (r.decode_len - 1) / max_batch * weight_read_s)
         if t > seconds:
@@ -267,10 +238,7 @@ def test_backlog_outlasts_the_roofline(name):
     peaks could finish within the ramp and the window, so a faster
     program still finds work waiting at the close."""
     cell = spec.load_cell(name)
-    s = flops.Shapes.from_config(cell.config["model"])
     seconds = cell.traffic["ramp_s"] + BENCHMARK["run_seconds"]
-    could = roofline_finished(cell.traffic, s,
-                              cell.config["engine"]["max_batch"],
-                              peaks.peak_for(CHIP), seconds)
+    could = roofline_finished(cell, peaks.peak_for(CHIP), seconds)
     assert could > 0
     assert cell.traffic["arrival"]["requests"] >= 2 * could
